@@ -28,7 +28,6 @@ import sys, json, dataclasses
 sys.path.insert(0, 'src')
 import jax, jax.numpy as jnp
 from repro.configs import get_config
-from repro.compat import cost_analysis_compat
 from repro.core.analytic import shape_cost
 from repro.core.hw import TPU_V5E
 from repro.distributed import sharding as SH
@@ -61,7 +60,7 @@ def measure_train(cfg, arch, layout='tp', zero1=False, strategy=None,
         'hlo_ici_static': coll['ici_traffic_bytes'],
         'mem_args_gib': ma.argument_size_in_bytes / 2**30,
         'mem_temp_gib': ma.temp_size_in_bytes / 2**30,
-        'hlo_flops': cost_analysis_compat(compiled).get('flops'),
+        'hlo_flops': compiled.cost_analysis().get('flops'),
     }
 
 def terms(cb):

@@ -6,8 +6,9 @@ Two modes:
   data axes; tensor parallelism stays GSPMD-auto over ``model``.  Gradient
   synchronisation is *explicit*: one ``psum`` per AllReduce bucket of the
   searched :class:`GradSyncStrategy`, with optional
-  ``optimization_barrier`` fences pinning the bucket schedule.  The compiled
-  HLO therefore carries exactly the collective schedule the search chose.
+  ``optimization_barrier`` fences pinning the bucket schedule.  The lowered
+  HLO therefore carries exactly the collective schedule the search chose;
+  XLA's all-reduce combiner may still merge buckets (DESIGN.md Sec. 13).
 
 * ``fsdp_tp`` — GSPMD-auto ZeRO-3 for architectures whose replicated
   weights+optimizer do not fit one TP shard (DeepSeek-V2-236B,
@@ -26,8 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compat
-from ..compat import axis_size_compat, shard_map_compat
 from ..models import model as M
 from ..models.config import ModelConfig
 from ..optim import adamw, apply_updates, clip_by_global_norm
@@ -164,9 +163,7 @@ def _fused_bucket_sync(leaves, dp: int, chunks: int, dp_axes,
     shard-tiled f32 staging, cast fused) -> per-chunk real reduce-scatter +
     mean + all-gather -> Pallas unpack (f32 -> grad dtype cast fused into
     the un-staging pass).  The wire arithmetic is exactly the ``rs_ag``
-    lowering's, so numerics match the fused ``psum`` bit-for-bit.  Raises
-    at trace time when Pallas cannot trace inside this shard_map region;
-    the caller falls back to the jnp RS+AG lowering (same numerics)."""
+    lowering's, so numerics match the fused ``psum`` bit-for-bit."""
     from ..kernels import ops as K
     total = sum(l.size for l in leaves)
     k = min(max(int(chunks), 1), max(total, 1))
@@ -187,7 +184,7 @@ def _fused_bucket_sync(leaves, dp: int, chunks: int, dp_axes,
 
 
 def sync_grads(grads, strategy: GradSyncStrategy, dp_axes: Sequence[str],
-               mesh=None, pspecs=None, full_manual: bool = False):
+               mesh=None, pspecs=None):
     """Explicit bucketed gradient synchronisation (mean) — DisCo tensor
     fusion with the searched per-bucket comm kind enacted.
 
@@ -213,57 +210,38 @@ def sync_grads(grads, strategy: GradSyncStrategy, dp_axes: Sequence[str],
     :func:`_fused_bucket_sync`: Pallas staging kernels pack the leaves
     straight into the reduce-scatter's chunked shard-tiled layout and
     unpack straight out of the all-gather with the dtype cast fused, with
-    the identical RS+AG wire arithmetic in between.  Where Pallas or
-    gather-type collectives cannot lower, the bucket falls down the same
-    ladder as ``rs_ag`` (jnp RS+AG, then fused ``psum``) — numerics are
-    preserved on every rung.
-
-    Compat gate: stock JAX 0.4.x's bundled XLA aborts on gather-type
-    collectives (``all_gather``/``all_to_all``/``ppermute``) inside a
-    *partial*-manual shard_map region (reduce-type ops are fine); in a
-    fully-manual region (``full_manual=True`` — no auto axes, e.g. the
-    ``layout="dp"`` step or TP degree 1) and on modern JAX the real RS+AG
-    pair lowers.  Where it cannot, ``rs_ag`` buckets fall back to the fused
-    ``psum`` — same numerics, AllReduce-shaped traffic (the same class of
-    0.4.x fallback as the vocab-parallel CE; see ``repro/compat.py``).
+    the identical RS+AG wire arithmetic in between.  A kernel that cannot
+    lower raises; there is no silent fallback.
 
     Fusing must not destroy tensor-parallel sharding, so when ``mesh`` and
     ``pspecs`` are given the bucketing runs inside a nested ``shard_map``
     over the ``model`` axis: the fused buffer concatenates the *local TP
     shards* (Megatron-DDP style), keeping the collective 1/TP-sized and the
-    HLO free of gather/reshard traffic.
+    HLO free of gather/reshard traffic.  It does so at TP degree 1 too: the
+    nesting leaves no mesh axis automatic, and a Mosaic kernel can only
+    lower where none is.
     """
     leaves, treedef = jax.tree_util.tree_flatten(grads)
 
     def fuse_and_reduce(leaves_local: list):
         dp = 1
         for a in dp_axes:
-            dp *= axis_size_compat(a)
+            dp *= jax.lax.axis_size(a)
         out: list = [None] * len(leaves_local)
         prev_fused = None
         for bi, bucket in enumerate(strategy.buckets):
-            gather_ok = (full_manual
-                         or not compat.needs_partial_manual_workarounds())
-            # searched in-kernel fused path: Pallas staging kernels around
-            # a real RS+AG pair.  The ladder: Pallas kernel path -> (when
-            # Pallas cannot trace in this region) the jnp RS+AG lowering
-            # below -> (when gather-type ops cannot lower at all) the fused
-            # psum — every rung loss-bit-identical.
-            want_fused = strategy.is_fused(bi) and dp > 1 and gather_ok
-            if want_fused:
-                try:
-                    outs, packed = _fused_bucket_sync(
-                        [leaves_local[i] for i in bucket], dp,
-                        strategy.chunk_count(bi), dp_axes,
-                        barrier_with=(prev_fused if strategy.barriers
-                                      else None))
-                except Exception:
-                    pass  # Pallas unavailable here -> jnp RS+AG below
-                else:
-                    for i, o in zip(bucket, outs):
-                        out[i] = o
-                    prev_fused = packed
-                    continue
+            if strategy.is_fused(bi) and dp > 1:
+                # searched in-kernel fused path: Pallas staging kernels
+                # around a real RS+AG pair
+                outs, packed = _fused_bucket_sync(
+                    [leaves_local[i] for i in bucket], dp,
+                    strategy.chunk_count(bi), dp_axes,
+                    barrier_with=(prev_fused if strategy.barriers
+                                  else None))
+                for i, o in zip(bucket, outs):
+                    out[i] = o
+                prev_fused = packed
+                continue
             flats = [leaves_local[i].reshape(-1) for i in bucket]
             fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
             if strategy.barriers and prev_fused is not None:
@@ -272,8 +250,7 @@ def sync_grads(grads, strategy: GradSyncStrategy, dp_axes: Sequence[str],
             # around an XLA:CPU bf16 all-reduce miscompile in the dry-run.
             dt = fused.dtype
             f32 = fused.astype(jnp.float32)
-            rs_ag = ((strategy.comm_kind(bi) == "rs_ag" or want_fused)
-                     and dp > 1 and gather_ok)
+            rs_ag = strategy.comm_kind(bi) == "rs_ag" and dp > 1
 
             def reduce_one(part):
                 if rs_ag:
@@ -310,22 +287,18 @@ def sync_grads(grads, strategy: GradSyncStrategy, dp_axes: Sequence[str],
                 off += n
         return tuple(out)
 
-    if (mesh is None or "model" not in mesh.shape or mesh.shape["model"] == 1
-            or not compat.supports_nested_partial_manual()):
-        # flat path: psum over the data axes on the (model-auto-sharded)
-        # gradients directly — also the 0.4.x route, which cannot nest a
-        # partial-manual shard_map over `model` inside the data region
+    if mesh is None or "model" not in mesh.shape:
+        # every mesh axis is a manual data axis already
         return jax.tree_util.tree_unflatten(treedef, fuse_and_reduce(leaves))
 
     specs = tuple(jax.tree_util.tree_leaves(
         pspecs, is_leaf=lambda x: isinstance(x, P)))
     assert len(specs) == len(leaves)
-    # nested shard_map picks up the ambient (partial-manual) mesh context on
-    # modern JAX; 0.4.x nests with the explicit mesh instead
-    synced = shard_map_compat(
+    # the nested shard_map picks up the ambient (partial-manual) mesh
+    synced = jax.shard_map(
         lambda *ls: fuse_and_reduce(list(ls)),
-        mesh=mesh, in_specs=specs, out_specs=specs,
-        axis_names={"model"}, check=False, use_ambient_mesh=True,
+        in_specs=specs, out_specs=specs, axis_names={"model"},
+        check_vma=False,
     )(*leaves)
     return jax.tree_util.tree_unflatten(treedef, list(synced))
 
@@ -369,11 +342,8 @@ def build_train_step(
     # shard_map is not nested inside a manual region (fsdp/auto mode);
     # the non-VP chunked CE is used there instead (see DESIGN.md).
     # In pure-DP layout everything is replicated: no vocab parallelism.
-    # JAX 0.4.x cannot nest the partial-manual VP shard_map at all — the
-    # same non-VP chunked CE fallback applies there.
-    nested_ok = compat.supports_nested_partial_manual()
-    vp_ce = mode == "ddp_tp" and layout != "dp" and nested_ok
-    vp = None if (layout == "dp" or not nested_ok) else mesh
+    vp_ce = mode == "ddp_tp" and layout != "dp"
+    vp = None if layout == "dp" else mesh
 
     def local_loss(params, batch):
         return loss_fn(params, cfg, batch, remat=remat, vp_mesh=vp,
@@ -391,7 +361,7 @@ def build_train_step(
             zero = (jnp.zeros(()),
                     jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                  params))
-            (loss, grads), _ = compat.scan_compat(body, zero, micro)
+            (loss, grads), _ = jax.lax.scan(body, zero, micro)
             scale = 1.0 / grad_accum
             return loss * scale, jax.tree.map(lambda g: g * scale, grads)
         return jax.value_and_grad(local_loss)(params, batch)
@@ -408,11 +378,10 @@ def build_train_step(
         def local_step(params, opt_state, batch):
             loss, grads = grads_of(params, batch)
             if layout == "dp":
-                # every mesh axis is a (manual) data axis here: the region
-                # is fully manual, so RS+AG lowering is safe on 0.4.x too
+                # every mesh axis is a (manual) data axis here
                 grads = sync_grads(
                     grads, strat or GradSyncStrategy.per_tensor(params),
-                    dp_axes, mesh=None, full_manual=True)
+                    dp_axes)
             else:
                 align = SH.head_alignment(cfg, mesh)
                 pspecs = jax.tree_util.tree_map_with_path(
@@ -422,8 +391,7 @@ def build_train_step(
                     grads)
                 grads = sync_grads(
                     grads, strat or GradSyncStrategy.per_tensor(params),
-                    dp_axes, mesh=mesh, pspecs=pspecs,
-                    full_manual=mesh.shape.get("model", 1) == 1)
+                    dp_axes, mesh=mesh, pspecs=pspecs)
             loss = jax.lax.pmean(loss, tuple(dp_axes))
             return update(params, opt_state, loss, grads)
 
@@ -432,12 +400,10 @@ def build_train_step(
             for k in batch_keys:
                 lead = dp_axes if len(dp_axes) > 1 else dp_axes[0]
                 bspec[k] = P(lead)
-            fn = shard_map_compat(local_step, mesh=mesh,
-                                  in_specs=(P(), P(), bspec),
-                                  out_specs=(P(), P(), P()),
-                                  axis_names=set(dp_axes),
-                                  check=False)
-            return fn
+            return jax.shard_map(local_step, mesh=mesh,
+                                 in_specs=(P(), P(), bspec),
+                                 out_specs=(P(), P(), P()),
+                                 axis_names=set(dp_axes), check_vma=False)
 
         def step(params, opt_state, batch):
             return make(tuple(sorted(batch)))(params, opt_state, batch)

@@ -2,8 +2,9 @@
 
 Each kernel ships three artifacts:
   <name>.py — ``pl.pallas_call`` with explicit BlockSpec VMEM tiling (TPU
-              target; validated with ``interpret=True`` on CPU),
-  ops.py    — jit'd public wrappers that pick kernel vs reference path,
+              target; every kernel takes ``interpret`` explicitly),
+  ops.py    — jit'd public wrappers; they run the kernels compiled on an
+              accelerator and in the Pallas interpreter on the CPU,
   ref.py    — pure-jnp oracles the tests assert against.
 
 Kernels: flash_attention (GQA / causal / sliding-window), rglru (RG-LRU

@@ -12,20 +12,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_TILE = 32 * 128  # elements in one TPU tile of the narrowest (8-bit) dtype
 
 def _kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].astype(o_ref.dtype)
 
 
-def convert_copy_kernel(x, out_dtype=jnp.float32, block: int = 65536,
-                        interpret: bool = True):
+def convert_copy_kernel(x, out_dtype=jnp.float32, block: int = 65536, *,
+                        interpret: bool):
     """Tiled convert-copy of a flat array (the per-leaf staging primitive).
 
     x: (N,) any float dtype; returns (N,) ``out_dtype``.  N is padded up to
     a block multiple internally.
     """
     n = x.shape[0]
-    block = min(block, max(n, 8))
+    # a block of whole (32, 128) tiles: Mosaic refuses a 1-D block that
+    # ends inside a tile of the output (e.g. 896 elements of bf16)
+    block = min(block, -(-max(n, 1) // _TILE) * _TILE)
     pad = (-n) % block
     if pad:
         x = jnp.pad(x, (0, pad))
@@ -41,8 +44,8 @@ def convert_copy_kernel(x, out_dtype=jnp.float32, block: int = 65536,
     return out[:n]
 
 
-def bucket_pack_kernel(leaves, total: int, out_dtype=jnp.float32,
-                       interpret: bool = True):
+def bucket_pack_kernel(leaves, total: int, out_dtype=jnp.float32, *,
+                       interpret: bool):
     """Stage a bucket of gradient leaves into one fused f32 buffer."""
     parts = [convert_copy_kernel(l.reshape(-1), out_dtype,
                                  interpret=interpret) for l in leaves]

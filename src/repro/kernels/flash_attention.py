@@ -82,7 +82,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_kernel(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
                            qb: int = 128, kb: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (B, S, H, hd); k, v: (B, T, KV, hd) -> (B, S, H, hd)."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]

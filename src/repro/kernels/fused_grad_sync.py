@@ -38,7 +38,7 @@ def chunk_cuts(total: int, chunks: int) -> list[int]:
 
 
 def fused_pack_kernel(leaves, total: int, dp: int, chunks: int = 1,
-                      block: int = 65536, interpret: bool = True):
+                      block: int = 65536, *, interpret: bool):
     """Stage a bucket of gradient leaves into reduce-scatter-ready chunks.
 
     Returns a list of ``chunks`` f32 buffers: chunk ``c`` covers byte range
@@ -63,8 +63,8 @@ def fused_pack_kernel(leaves, total: int, dp: int, chunks: int = 1,
     return out
 
 
-def fused_unpack_kernel(buf, shapes, dtypes, block: int = 65536,
-                        interpret: bool = True):
+def fused_unpack_kernel(buf, shapes, dtypes, block: int = 65536, *,
+                        interpret: bool):
     """Un-stage the gathered f32 bucket back into leaves, the f32 ->
     grad-dtype cast fused into the same tiled pass (all-gather epilogue)."""
     out = []

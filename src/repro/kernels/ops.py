@@ -1,14 +1,15 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode (the
-kernel body runs in Python via the Pallas interpreter — numerically
-identical to the TPU lowering).  On a real TPU set
-``REPRO_KERNEL_INTERPRET=0`` (or call ``set_interpret(False)``) to compile
-the Mosaic kernels.
+The platform picks how a kernel runs.  On an accelerator it compiles to a
+Mosaic kernel (``tpu_custom_call`` in the HLO); on the CPU, where Mosaic
+cannot lower, it runs in the Pallas interpreter (the kernel body in plain
+JAX ops, numerically identical).  There is no switch: tests on the CPU
+interpret, runs on the chip compile, and a kernel the chip's compiler
+refuses raises.  ``tests/test_tpu_compile.py`` compiles the kernels for a
+described TPU without one attached.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
@@ -22,12 +23,10 @@ from . import bucket_pack as _bp
 from . import fused_grad_sync as _fg
 from . import ref as _ref
 
-_INTERPRET = os.environ.get("REPRO_KERNEL_INTERPRET", "1") != "0"
 
-
-def set_interpret(flag: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = flag
+def _interpret() -> bool:
+    """Interpret on the CPU backend, compile everywhere else."""
+    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window"))
@@ -35,7 +34,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None):
     """GQA flash attention.  q: (B,S,H,hd); k,v: (B,T,KV,hd)."""
     return _fa.flash_attention_kernel(q, k, v, causal=causal, window=window,
-                                      interpret=_INTERPRET)
+                                      interpret=_interpret())
 
 
 @jax.jit
@@ -45,32 +44,32 @@ def rglru_scan(x, r_gate, i_gate, lam, c: float = 8.0):
     log_a = -c * jax.nn.softplus(lam)[None, None, :] * r_gate
     a = jnp.exp(log_a)
     g = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12)) * (i_gate * x)
-    return _rg.rglru_scan_kernel(a, g, interpret=_INTERPRET)
+    return _rg.rglru_scan_kernel(a, g, interpret=_interpret())
 
 
 @jax.jit
 def rwkv6_wkv(r, k, v, w, u):
     """WKV-6.  r,k,v,w: (B,S,H,hd); u: (H,hd)."""
-    return _rk.rwkv6_wkv_kernel(r, k, v, w, u, interpret=_INTERPRET)
+    return _rk.rwkv6_wkv_kernel(r, k, v, w, u, interpret=_interpret())
 
 
 def bucket_pack(leaves, total: int, out_dtype=jnp.float32):
     return _bp.bucket_pack_kernel(leaves, total, out_dtype,
-                                  interpret=_INTERPRET)
+                                  interpret=_interpret())
 
 
 def fused_pack(leaves, total: int, dp: int, chunks: int = 1):
     """Reduce-scatter-ready chunked staging of a fused bucket (the
     in-kernel compute+comm overlap path's pack half)."""
     return _fg.fused_pack_kernel(leaves, total, dp, chunks,
-                                 interpret=_INTERPRET)
+                                 interpret=_interpret())
 
 
 def fused_unpack(buf, shapes, dtypes):
     """All-gather epilogue: un-stage the gathered f32 bucket back into
     leaves with the dtype cast fused (the overlap path's unpack half)."""
     return _fg.fused_unpack_kernel(buf, shapes, dtypes,
-                                   interpret=_INTERPRET)
+                                   interpret=_interpret())
 
 
 # re-exported oracles (tests assert kernel == ref)

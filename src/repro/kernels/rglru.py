@@ -38,7 +38,7 @@ def _kernel(a_ref, g_ref, h_ref, state, *, tc: int):
 
 
 def rglru_scan_kernel(a, g, *, tc: int = 128, lb: int = 512,
-                      interpret: bool = True):
+                      interpret: bool):
     """a, g: (B, S, L) decay and gated input; returns h: (B, S, L).
 
     h_t = a_t * h_{t-1} + g_t  (the caller precomputes a = exp(log_a) and

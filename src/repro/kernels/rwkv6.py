@@ -41,7 +41,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state, *, tc: int):
     state[...] = jax.lax.fori_loop(0, tc, step, state[...])
 
 
-def rwkv6_wkv_kernel(r, k, v, w, u, *, tc: int = 128, interpret: bool = True):
+def rwkv6_wkv_kernel(r, k, v, w, u, *, tc: int = 128, interpret: bool):
     """r,k,v,w: (B, S, H, hd); u: (H, hd) -> out (B, S, H, hd)."""
     B, S, H, hd = r.shape
     tc = min(tc, S)

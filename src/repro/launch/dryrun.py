@@ -20,8 +20,12 @@ including a
 the preset zoo; default derives the topology from the mesh).
 """
 import os
-# MUST run before any jax import: device count locks on first init.
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+
+if __name__ == "__main__":
+    # run as a program: 512 placeholder host devices for the pod meshes
+    # (before jax initialises; importing this module sets nothing)
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
 
 import argparse
 import json
@@ -59,6 +63,8 @@ _COLL_RE = re.compile(
     r"(?:-start|-done)?\(")
 _GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUPS_OLD_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
+# long tuple shapes carry /*index=N*/ markers, whose '=' would cut _COLL_RE
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 
 def _shape_bytes(text: str) -> float:
@@ -89,6 +95,7 @@ def parse_collectives(hlo_text: str) -> dict:
     per_op: dict = {}
     traffic = 0.0
     for line in hlo_text.splitlines():
+        line = _COMMENT_RE.sub("", line)
         m = _COLL_RE.search(line)
         if m is None or "-done(" in line:
             continue
@@ -169,11 +176,9 @@ def build_dryrun_prefill(cfg, mesh, shape: str, fsdp: bool = False):
     cache_specs = jax.tree.map(
         lambda l: P(None, lead, *([None] * (l.ndim - 2))), out_shape[1])
     bspec = {k: P(lead) for k in specs}
-    from repro.compat import shard_map_compat
-
-    smfn = shard_map_compat(fn, mesh=mesh, in_specs=(P(), bspec),
-                            out_specs=(logits_spec, cache_specs),
-                            axis_names=set(dp_axes), check=False)
+    smfn = jax.shard_map(fn, mesh=mesh, in_specs=(P(), bspec),
+                         out_specs=(logits_spec, cache_specs),
+                         axis_names=set(dp_axes), check_vma=False)
     # NOTE: under the data-manual region, params must not be data-sharded
     # (they enter with spec P()); big-arch serving shards experts over
     # `model` only — weights stream from the EP shards.
@@ -697,9 +702,7 @@ def dryrun_one(arch: str, shape: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.perf_counter() - t0
 
-    from ..compat import cost_analysis_compat
-
-    ca = cost_analysis_compat(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     coll = parse_collectives(compiled.as_text())
     # price the collectives on the requested preset, or on the topology the

@@ -1,18 +1,21 @@
-"""Production mesh builders.
+"""Mesh builders.
+
+``make_device_mesh`` lays the devices this process sees out as
+``(data, model)`` — every device on ``data`` by default; the trainer and
+``chip_smoke.py`` use it on one chip, four chips or forced host devices
+alike.  ``make_production_mesh`` builds the pod meshes the dry-run prices:
 
 Single pod: 16x16 = 256 chips, axes (data, model).
 Multi pod:  2x16x16 = 512 chips, axes (pod, data, model).
 
-``make_production_mesh`` is a FUNCTION (importing this module never touches
-jax device state).  The dry-run launcher sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before importing
-jax so 512 placeholder host devices exist; smoke tests and benches see the
-default single device.
+Every builder is a FUNCTION (importing this module never touches jax
+device state).  The dry-run program sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before jax
+initialises so 512 placeholder host devices exist.
 
-``make_mesh_compat`` papers over the JAX API skew around mesh axis types:
-JAX >= 0.5 grew ``jax.sharding.AxisType`` and a ``jax.make_mesh(...,
-axis_types=...)`` keyword; on stock JAX 0.4.x neither exists and every mesh
-axis is implicitly "auto" — so the fallback simply omits the argument.
+``make_mesh`` is ``jax.make_mesh`` with every axis ``AxisType.Auto``: the
+train step goes manual over the data axes with ``shard_map`` and leaves
+tensor parallelism to GSPMD over ``model``.
 
 ``cluster_from_mesh`` bridges a mesh to the topology model of
 :mod:`repro.cluster` (intra-pod axes -> one ICI level, a ``pod`` axis -> an
@@ -22,31 +25,24 @@ interconnect the mesh actually spans.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with ``axis_types=(AxisType.Auto, ...)`` where the
-    installed JAX supports it, plain ``jax.make_mesh`` (or the ``Mesh``
-    constructor) otherwise."""
-    try:
-        from jax.sharding import AxisType  # JAX >= 0.5
-        axis_types = (AxisType.Auto,) * len(axes)
-    except ImportError:
-        axis_types = None
-    if hasattr(jax, "make_mesh"):
-        if axis_types is not None:
-            try:
-                return jax.make_mesh(shape, axes, devices=devices,
-                                     axis_types=axis_types)
-            except TypeError:
-                pass  # make_mesh predates the axis_types kwarg
-        return jax.make_mesh(shape, axes, devices=devices)
-    # very old JAX: build the Mesh directly
-    import numpy as np
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
-    devs = list(devices) if devices is not None else jax.devices()
-    n = int(np.prod(shape))
-    return jax.sharding.Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+
+def make_device_mesh(model: int = 1):
+    """``(data, model)`` mesh over every device this process sees:
+    ``model``-way tensor parallelism, the rest data parallel."""
+    devices = jax.devices()
+    if len(devices) % model:
+        raise ValueError(f"{len(devices)} devices do not split into "
+                         f"model={model}")
+    return make_mesh((len(devices) // model, model), ("data", "model"),
+                     devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -59,7 +55,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=512 before importing "
             f"jax); have {len(devices)}")
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def cluster_from_mesh(mesh, hw=None):
@@ -88,14 +84,3 @@ def cluster_from_mesh(mesh, hw=None):
     name = "mesh_" + "x".join(str(s) for s in
                               ([pods] if pods > 1 else []) + list(shape.values()))
     return ClusterSpec(name, levels)
-
-
-def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh over forced host devices for integration tests."""
-    import numpy as np
-
-    n = int(np.prod(shape))
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return make_mesh_compat(shape, axes, devices=devices[:n])

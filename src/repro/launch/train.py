@@ -1,21 +1,23 @@
-"""End-to-end training driver.
+"""End-to-end training driver, on whatever devices this process sees.
 
-    PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
-        --reduced --steps 300 --batch 16 --seq 64 --strategy auto
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
+        --steps 10 --batch 8 --seq 1024 --strategy auto
 
 Pipeline: synthetic data -> (optional) DisCo strategy search on the traced
-step -> DisCo-enacted distributed train step (bucketed psum) -> checkpoints.
-On this CPU container use ``--reduced`` (full configs are dry-run only);
-``--mesh debug`` uses a small forced-host-device mesh, ``--mesh single``
-runs on one device (mesh 1x1).
+step -> DisCo-enacted distributed train step (bucketed gradient sync) ->
+checkpoints.  The mesh is ``(data, model)`` over ``jax.devices()``: every
+device on ``data`` unless ``--model`` asks for tensor parallelism.  On a
+TPU the configs run at their published widths (``chip_smoke.py`` drives
+qwen2-0.5b this way); on the CPU pass ``--reduced`` for a config cut to
+test size, and set ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+from outside for ``N`` host devices.
+
+Compiled programs are cached in ``$JAX_COMPILATION_CACHE_DIR`` when it is
+set, and in ``<checkout>/.jax_cache`` otherwise (:func:`use_compile_cache`).
 """
-import os
-
-if "XLA_FLAGS" not in os.environ:  # before jax import; see dryrun.py
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import argparse
-import json
+import os
+import pathlib
 import time
 
 import jax
@@ -31,12 +33,37 @@ from ..distributed.train_step import (GradSyncStrategy, build_train_step,
                                       jit_train_step)
 from ..models import stacked as ST
 from ..optim import adamw, linear_warmup_cosine
-from .mesh import make_debug_mesh
+from .mesh import make_device_mesh
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, where set, is read by JAX itself and
+    nothing else is set.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``: the directory is part of the cache key, so
+    a path that moved between runs would never hit.  Entry points call
+    this; library imports never do."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_params(cfg, seed: int):
+    """The model's parameters from ``seed``, initialised on the device in
+    one compiled program (the same values on every call)."""
+    return jax.jit(ST.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
 
 
 def search_strategy(cfg, params, batch, n_devices: int,
                     unchanged_limit: int = 80, seed: int = 0, cluster=None):
-    """Trace the step on the *actual* training batch and run the DisCo
+    """Trace the step on the per-device training batch and run the DisCo
     search through the ``repro.plan.compile`` facade.  ``cluster`` (a
     preset name or ClusterSpec) prices collectives on that topology;
     default is the legacy flat model.  Returns (strategy, Plan)."""
@@ -49,15 +76,73 @@ def search_strategy(cfg, params, batch, n_devices: int,
     return plan.grad_sync(params), plan
 
 
-def main():
+def choose_strategy(kind: str, cfg, params, local_batch, dp: int, *,
+                    cluster=None, strategy_file=None):
+    """The gradient-sync strategy a run enacts; returns (strategy, Plan or
+    None).  ``kind`` is ``auto`` (DisCo search), ``ddp`` (25 MiB size-capped
+    buckets), ``single-bucket`` or ``per-tensor``."""
+    if strategy_file:
+        # Plan.load reads both repro.plan artifacts and legacy
+        # strategy.json files (migration shim)
+        plan = RP.Plan.load(strategy_file)
+        strat = plan.grad_sync(params)
+        print(f"loaded strategy: {len(strat.buckets)} buckets")
+        return strat, plan
+    if kind == "auto":
+        t0 = time.perf_counter()
+        strat, plan = search_strategy(cfg, params, local_batch, n_devices=dp,
+                                      cluster=cluster)
+        prov = plan.provenance
+        print(f"DisCo search: {prov['initial_cost'] * 1e6:.1f} -> "
+              f"{prov['best_cost'] * 1e6:.1f} us simulated; "
+              f"plan buckets {len(strat.buckets)} over "
+              f"{prov['grad_tensors']} gradient tensors; search "
+              f"simulations {prov['simulations']}; search seconds "
+              f"{time.perf_counter() - t0:.3f}")
+        return strat, plan
+    if kind == "ddp":
+        return GradSyncStrategy.size_capped(params), None
+    if kind == "single-bucket":
+        return GradSyncStrategy.single_bucket(params), None
+    if kind == "per-tensor":
+        return GradSyncStrategy.per_tensor(params), None
+    raise ValueError(f"unknown strategy {kind!r}")
+
+
+def compile_step(cfg, mesh, strat, params, opt, batch, optimizer):
+    """Build, jit and AOT-compile the ``ddp_tp`` train step for ``batch``'s
+    shapes.  Returns (compiled step, compile seconds)."""
+    step_fn = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat,
+                               optimizer=optimizer, remat=True)
+    specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+             for k, v in batch.items()}
+    jf = jit_train_step(step_fn, cfg, mesh, params, opt, specs)
+    t0 = time.perf_counter()
+    compiled = jf.lower(params, opt, specs).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def step_batch(example: dict, ds: SyntheticLMDataset, step: int,
+               vocab: int) -> dict:
+    """The global batch of ``step``: fresh synthetic tokens, the rest of
+    ``example`` (stubbed modality inputs) unchanged."""
+    batch = dict(example)
+    batch["tokens"] = jnp.asarray(ds.global_step_batch(step) % vocab)
+    return batch
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="the config cut to test size (CPU runs)")
     ap.add_argument("--steps", type=int, default=300)
-    ap.add_argument("--batch", type=int, default=16)
+    ap.add_argument("--batch", type=int, default=16, help="global batch")
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--mesh", default="debug", choices=["debug", "single"])
+    ap.add_argument("--model", type=int, default=1,
+                    help="tensor-parallel degree; the other devices are "
+                         "data parallel")
     ap.add_argument("--strategy", default="auto",
                     choices=["auto", "per-tensor", "ddp", "single-bucket"],
                     help="auto = DisCo backtracking search")
@@ -73,53 +158,43 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None) -> dict:
+    """Run the driver; returns its ``losses`` and per-step ``step_ms``
+    (each step ends in ``block_until_ready``)."""
+    args = parse_args(argv)
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    mesh = make_debug_mesh((4, 2) if args.mesh == "debug" else (1, 1))
+    mesh = make_device_mesh(model=args.model)
     dp = mesh.shape["data"]
-    assert args.batch % dp == 0
+    if args.batch % dp:
+        raise ValueError(f"--batch {args.batch} does not split over "
+                         f"{dp} data-parallel devices")
 
-    key = jax.random.PRNGKey(args.seed)
-    params = ST.init_params(key, cfg)
+    params = init_params(cfg, args.seed)
     n_params = sum(x.size for x in jax.tree.leaves(params))
-    print(f"arch={cfg.name} params={n_params / 1e6:.2f}M mesh={dict(mesh.shape)}")
+    print(f"arch={cfg.name} reduced={args.reduced} "
+          f"params={n_params / 1e6:.2f}M d_model={cfg.d_model} "
+          f"layers={cfg.n_layers} vocab={cfg.vocab} mesh={dict(mesh.shape)} "
+          f"devices={jax.devices()[0].platform}x{len(jax.devices())}")
 
     sched = linear_warmup_cosine(args.lr, warmup=20, total_steps=args.steps)
-    opt_init, opt_update = adamw(sched, weight_decay=0.01)
-    opt = opt_init(jax.tree.map(lambda p: p.astype(jnp.float32), params))
+    optimizer = adamw(sched, weight_decay=0.01)
+    opt = optimizer[0](jax.tree.map(lambda p: p.astype(jnp.float32), params))
 
     ds = SyntheticLMDataset(cfg.vocab, args.seq, args.batch, seed=args.seed)
     example = materialize_batch(cfg, args.batch, args.seq, seed=args.seed)
-
-    if args.strategy_file:
-        # Plan.load reads both repro.plan artifacts and legacy
-        # strategy.json files (migration shim)
-        strat = RP.Plan.load(args.strategy_file).grad_sync(params)
-        print(f"loaded strategy: {len(strat.buckets)} buckets")
-    elif args.strategy == "auto":
-        t0 = time.time()
-        strat, plan = search_strategy(cfg, params, example, n_devices=dp,
-                                      cluster=args.cluster)
-        prov = plan.provenance
-        print(f"DisCo search: {prov['initial_cost'] * 1e6:.1f} -> "
-              f"{prov['best_cost'] * 1e6:.1f} us simulated "
-              f"({prov['simulations']} sims, {time.time() - t0:.1f}s); "
-              f"{len(strat.buckets)} AllReduce buckets")
-    elif args.strategy == "ddp":
-        strat = GradSyncStrategy.size_capped(params)
-    elif args.strategy == "single-bucket":
-        strat = GradSyncStrategy.single_bucket(params)
-    else:
-        strat = GradSyncStrategy.per_tensor(params)
-
-    step_fn = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat,
-                               optimizer=(opt_init, opt_update), remat=True)
-    specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
-             for k, v in example.items()}
-    jf = jit_train_step(step_fn, cfg, mesh, params, opt, specs)
+    local = {k: v[:args.batch // dp] for k, v in example.items()}
+    strat, _ = choose_strategy(args.strategy, cfg, params, local, dp,
+                               cluster=args.cluster,
+                               strategy_file=args.strategy_file)
+    step, compile_s = compile_step(cfg, mesh, strat, params, opt, example,
+                                   optimizer)
+    print(f"compile seconds {compile_s:.3f}")
 
     start = 0
     if args.ckpt_dir:
@@ -130,27 +205,27 @@ def main():
         except FileNotFoundError:
             pass
 
-    losses = []
-    t0 = time.time()
-    for step in range(start, args.steps):
-        batch = dict(example)
-        batch["tokens"] = jnp.asarray(ds.global_step_batch(step) % cfg.vocab)
-        params, opt, metrics = jf(params, opt, batch)
+    losses, step_ms = [], []
+    for i in range(start, args.steps):
+        batch = step_batch(example, ds, i, cfg.vocab)
+        t0 = time.perf_counter()
+        params, opt, metrics = jax.block_until_ready(step(params, opt, batch))
+        step_ms.append((time.perf_counter() - t0) * 1e3)
         losses.append(float(metrics["loss"]))
-        if step % args.log_every == 0 or step == args.steps - 1:
-            dt = (time.time() - t0) / max(step - start + 1, 1)
-            print(f"step {step:5d}  loss {losses[-1]:.4f}  "
+        if i % args.log_every == 0 or i == args.steps - 1:
+            print(f"step {i:5d}  loss {losses[-1]:.4f}  "
                   f"gnorm {float(metrics['grad_norm']):.3f}  "
-                  f"{dt * 1e3:.0f} ms/step")
-        if args.ckpt_dir and step > start and step % args.ckpt_every == 0:
-            save_checkpoint(args.ckpt_dir, step, (params, opt))
+                  f"{step_ms[-1]:.1f} ms/step")
+        if args.ckpt_dir and i > start and i % args.ckpt_every == 0:
+            save_checkpoint(args.ckpt_dir, i, (params, opt))
     if args.ckpt_dir:
         save_checkpoint(args.ckpt_dir, args.steps, (params, opt))
-    first = np.mean(losses[:10])
-    last = np.mean(losses[-10:])
-    print(f"loss: first10 {first:.4f} -> last10 {last:.4f} "
-          f"({'improved' if last < first else 'NOT improved'})")
-    return losses
+    if losses:
+        first = np.mean(losses[:10])
+        last = np.mean(losses[-10:])
+        print(f"loss: first10 {first:.4f} -> last10 {last:.4f} "
+              f"({'improved' if last < first else 'NOT improved'})")
+    return {"losses": losses, "step_ms": step_ms}
 
 
 if __name__ == "__main__":

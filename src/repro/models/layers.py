@@ -463,9 +463,7 @@ def moe_fwd(p: dict, cfg: ModelConfig, x):
     xt = x.reshape(T, D)
     logits = (xt @ p["router"].astype(x.dtype)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    from ..compat import top_k_compat
-
-    topv, topi = top_k_compat(probs, k)                  # (T,k)
+    topv, topi = jax.lax.top_k(probs, k)                 # (T,k)
     topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-9)
     # load-balance aux loss (Switch-style)
     density = jnp.mean(jax.nn.one_hot(topi[:, 0], E), axis=0)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig
-from ..compat import scan_compat
 from . import layers as L
 from . import model as M
 
@@ -141,12 +140,12 @@ def _group_scan(x, gparams, cfg, g, positions, memory, use_kernels, remat,
         body = jax.checkpoint(body)
     if caches is None:
         # scan needs a pytree of xs with leading dim = count
-        (x, aux), ys = scan_compat(
+        (x, aux), ys = jax.lax.scan(
             lambda c, p: body(c, (p, None)),
             (x, jnp.zeros((), jnp.float32)), gparams)
     else:
-        (x, aux), ys = scan_compat(body, (x, jnp.zeros((), jnp.float32)),
-                                   (gparams, caches))
+        (x, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                    (gparams, caches))
     return x, aux, (ys if want_cache else None)
 
 
@@ -194,7 +193,7 @@ def encode(params, cfg: ModelConfig, frames):
         h2 = L.norm_fwd(lp["ln2"], cfg, x)
         return x + L.mlp_fwd(lp["mlp"], cfg, h2), 0
 
-    x, _ = scan_compat(body, x, enc["layers"])
+    x, _ = jax.lax.scan(body, x, enc["layers"])
     return L.norm_fwd(enc["final_norm"], cfg, x)
 
 
@@ -288,7 +287,7 @@ def loss_fn(params, cfg: ModelConfig, batch, *, use_kernels: bool = False,
           targets.reshape(B, nc, chunk).transpose(1, 0, 2),
           weights.reshape(B, nc, chunk).transpose(1, 0, 2))
     body = jax.checkpoint(ce_chunk) if remat else ce_chunk
-    (ce_sum, cnt), _ = scan_compat(body, (jnp.zeros(()), jnp.zeros(())), xs)
+    (ce_sum, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())), xs)
     return ce_sum / jnp.maximum(cnt, 1.0) + aux
 
 

@@ -20,25 +20,18 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def _inside_manual() -> bool:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or not m.shape:
-            return False
-        return any("Manual" in str(t) for t in m.axis_types)
-    except Exception:
-        return False
+    return AxisType.Manual in jax.sharding.get_abstract_mesh().axis_types
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    from repro.compat import shard_map_compat
-
-    return shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, axis_names={"model"},
-                            check=False, use_ambient_mesh=_inside_manual())
+    # inside a manual region the nested shard_map takes the ambient mesh
+    return jax.shard_map(fn, mesh=None if _inside_manual() else mesh,
+                         in_specs=in_specs, out_specs=out_specs,
+                         axis_names={"model"}, check_vma=False)
 
 
 def applicable(mesh, vocab: int) -> bool:

@@ -31,7 +31,7 @@ def test_ddp_tp_step_matches_single_device():
     trajectory as plain single-device training."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import (GradSyncStrategy, build_train_step,
@@ -61,7 +61,7 @@ for i in range(3):
     p_ref, o_ref, l = ref_step(p_ref, o_ref, batch)
     ref_losses.append(float(l))
 
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 strat = GradSyncStrategy.size_capped(params, 1 << 16)
 step = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat,
                         grad_accum=1, remat=True, lr=1e-3)
@@ -87,7 +87,7 @@ def test_bucketing_strategies_equivalent():
     gradients (tensor fusion must not change the math — paper Sec. 2.5)."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import GradSyncStrategy, build_train_step, jit_train_step
@@ -100,7 +100,7 @@ params = ST.init_params(key, cfg)
 init, _ = adamw(1e-3)
 opt = init(jax.tree.map(lambda p: p.astype(jnp.float32), params))
 batch = materialize_batch(cfg, 8, 32, seed=0)
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
 results = []
 for strat in (GradSyncStrategy.per_tensor(params),
@@ -124,13 +124,11 @@ print("EQUIV_OK")
 @pytest.mark.slow
 def test_rs_ag_bucket_lowering_matches_allreduce():
     """A ZeRO-3 ``rs_ag`` bucket enacts as reduce-scatter + all-gather in
-    the compiled HLO (fully-manual ``layout="dp"`` region — the lowering
-    0.4.x XLA can partition) and computes losses identical to the fused
-    AllReduce path; in the partial-manual TP layout the 0.4.x fallback
-    keeps numerics identical too."""
+    the compiled HLO (``layout="dp"``) and computes losses identical to the
+    fused AllReduce path, in the TP layout too."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import (GradSyncStrategy, build_train_step,
@@ -145,7 +143,7 @@ params = ST.init_params(key, cfg)
 init, _ = adamw(1e-3)
 opt = init(jax.tree.map(lambda p: p.astype(jnp.float32), params))
 batch = materialize_batch(cfg, 8, 32, seed=0)
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
 base = GradSyncStrategy.size_capped(params, 1 << 14)
 results = {}
@@ -167,8 +165,7 @@ assert results["rs_ag"][2].get("reduce-scatter", {}).get("count", 0) > 0
 assert results["rs_ag"][2].get("all-gather", {}).get("count", 0) > 0
 assert results["ar"][2].get("reduce-scatter", {}).get("count", 0) == 0
 
-# partial-manual TP layout: 0.4.x falls back to psum for rs_ag buckets,
-# modern JAX lowers the real pair -- either way the loss must match
+# TP layout: the pair lowers inside the nested model-manual region
 strat = GradSyncStrategy(base.buckets, comms=["rs_ag"] * len(base.buckets))
 step = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat, lr=1e-3)
 jf = jit_train_step(step, cfg, mesh, params, opt, specs)
@@ -183,14 +180,19 @@ print("RS_AG_OK")
 
 @pytest.mark.slow
 def test_chunked_bucket_enactment():
-    """A bucket with ``chunks=k`` enacts as k per-chunk collectives in the
-    compiled HLO — the collective count scales exactly with the chunk
-    count — while the loss and grad norm stay bit-identical (each
-    element's reduction is unchanged, only the op it rides in shrinks).
-    Covers both the fused-AllReduce and ZeRO-3 RS+AG lowering paths."""
+    """A bucket with ``chunks=k`` enacts as k per-chunk collectives: the
+    lowered program carries exactly ``k`` psums (or RS+AG pairs) per bucket
+    while the loss and grad norm stay bit-identical (each element's
+    reduction is unchanged, only the op it rides in shrinks).
+
+    XLA's all-reduce combiner then merges psums: the compiled program
+    carries fewer all-reduce ops than were issued (on the CPU, one tuple
+    all-reduce for every bucket and chunk), moving the same bytes.  So the
+    searched bucket schedule does not survive compilation for ``ar``
+    buckets; reduce-scatter / all-gather pairs do survive here."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import (GradSyncStrategy, build_train_step,
@@ -205,7 +207,7 @@ params = ST.init_params(key, cfg)
 init, _ = adamw(1e-3)
 opt = init(jax.tree.map(lambda p: p.astype(jnp.float32), params))
 batch = materialize_batch(cfg, 8, 32, seed=0)
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
 base = GradSyncStrategy.size_capped(params, 1 << 14)
 B = len(base.buckets)
@@ -216,18 +218,31 @@ for kind, k in (("ar", 1), ("ar", 4), ("rs_ag", 2)):
     step = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat,
                             lr=1e-3, layout="dp")
     jf = jit_train_step(step, cfg, mesh, params, opt, specs, layout="dp")
-    coll = parse_collectives(jf.lower(params, opt, specs).compile().as_text())
+    lowered = jf.lower(params, opt, specs)
+    issued = parse_collectives(lowered.as_text(dialect="hlo"))["per_op"]
+    compiled = parse_collectives(lowered.compile().as_text())["per_op"]
     p_in = jax.tree.map(jnp.array, params)
     o_in = jax.tree.map(jnp.array, opt)
     _, _, m = jf(p_in, o_in, batch)
     res[(kind, k)] = (float(m["loss"]), float(m["grad_norm"]),
-                      {op: d["count"] for op, d in coll["per_op"].items()})
+                      {op: (d["count"], d["bytes"]) for op, d in issued.items()},
+                      {op: (d["count"], d["bytes"]) for op, d in compiled.items()})
 print(res)
-# the collective count scales exactly with the chunk count ...
-ar1, ar4 = res[("ar", 1)][2], res[("ar", 4)][2]
-assert ar4["all-reduce"] - ar1["all-reduce"] == 3 * B, (ar1, ar4, B)
-rs2 = res[("rs_ag", 2)][2]
-assert rs2["reduce-scatter"] == 2 * B and rs2["all-gather"] == 2 * B, rs2
+ar1, ar4, rs2 = res[("ar", 1)], res[("ar", 4)], res[("rs_ag", 2)]
+# issued: one collective per chunk per bucket (+ the loss pmean) ...
+assert ar1[2]["all-reduce"][0] == B + 1, (ar1[2], B)
+assert ar4[2]["all-reduce"][0] == 4 * B + 1, (ar4[2], B)
+assert rs2[2]["reduce-scatter"][0] == 2 * B, rs2[2]
+assert rs2[2]["all-gather"][0] == 2 * B, rs2[2]
+# ... chunking moves the same bytes ...
+assert ar1[2]["all-reduce"][1] == ar4[2]["all-reduce"][1], (ar1, ar4)
+# ... the combiner merges the psums but keeps their bytes, and leaves the
+# RS+AG pairs alone
+for r in (ar1, ar4):
+    assert r[3]["all-reduce"][0] < r[2]["all-reduce"][0], r
+    assert r[3]["all-reduce"][1] == r[2]["all-reduce"][1], r
+assert rs2[3]["reduce-scatter"] == rs2[2]["reduce-scatter"], rs2
+assert rs2[3]["all-gather"] == rs2[2]["all-gather"], rs2
 # ... the psum path is bit-identical chunked vs whole, and the chunked
 # RS+AG split matches to collective-reassociation tolerance
 assert res[("ar", 4)][:2] == res[("ar", 1)][:2], res
@@ -245,11 +260,11 @@ def test_fused_bucket_enactment():
     unpack prologue) in the fully-manual ``layout="dp"`` region: the
     compiled HLO carries one RS/AG pair per chunk per bucket and the loss /
     grad norm match the plain AllReduce path to collective-reassociation
-    tolerance.  In the partial-manual TP layout the compat ladder falls all
-    the way back to psum with identical numerics."""
+    tolerance.  In the TP layout the kernels run inside the nested
+    model-manual region with the same numerics."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import (GradSyncStrategy, build_train_step,
@@ -264,7 +279,7 @@ params = ST.init_params(key, cfg)
 init, _ = adamw(1e-3)
 opt = init(jax.tree.map(lambda p: p.astype(jnp.float32), params))
 batch = materialize_batch(cfg, 8, 32, seed=0)
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
 base = GradSyncStrategy.size_capped(params, 1 << 14)
 B = len(base.buckets)
@@ -296,8 +311,7 @@ np.testing.assert_allclose(res["fused"][:2], res["ar"][:2], rtol=1e-4)
 np.testing.assert_allclose(res["fused"][:2], res["rs_ag"][:2], rtol=1e-4)
 np.testing.assert_allclose(res["fused_c2"][:2], res["ar"][:2], rtol=1e-4)
 
-# partial-manual TP layout: the compat ladder drops the kernel path and
-# keeps numerics identical to AllReduce
+# TP layout: the kernel path keeps numerics identical to AllReduce
 strat = GradSyncStrategy(base.buckets, comms=["ar"] * B, fused=[1] * B)
 step = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat, lr=1e-3)
 jf = jit_train_step(step, cfg, mesh, params, opt, specs)
@@ -314,9 +328,9 @@ print("FUSED_OK")
 def test_vocab_parallel_matches_dense():
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.models import vocab_parallel as VP
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 V, D, B, S = 64, 16, 2, 8
 embed = jax.random.normal(key, (V, D))
@@ -355,7 +369,7 @@ def test_dryrun_reduced_mesh():
     """End-to-end dryrun machinery on a small mesh + reduced config."""
     out = run_sub("""
 import jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import build_train_step, jit_train_step
@@ -364,7 +378,7 @@ from repro.launch.dryrun import parse_collectives
 from repro.data.pipeline import make_batch_specs
 
 cfg = get_config("deepseek-v2-lite-16b").reduced()
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 params = jax.eval_shape(lambda: ST.init_params(jax.random.PRNGKey(0), cfg))
 init, _ = adamw(1e-3)
 opt = jax.eval_shape(lambda: init(jax.tree.map(
@@ -374,8 +388,7 @@ step = build_train_step(cfg, mesh, mode="ddp_tp")
 jf = jit_train_step(step, cfg, mesh, params, opt, specs)
 lowered = jf.lower(params, opt, specs)
 compiled = lowered.compile()
-from repro.compat import cost_analysis_compat
-assert cost_analysis_compat(compiled).get("flops", 0) > 0
+assert compiled.cost_analysis().get("flops", 0) > 0
 coll = parse_collectives(compiled.as_text())
 assert coll["per_op"].get("all-reduce", {}).get("count", 0) > 0
 print("DRYRUN_OK", coll["per_op"]["all-reduce"]["count"])
@@ -400,6 +413,29 @@ def test_strategy_save_load(tmp_path):
     s3.save(p3)
     loaded = GradSyncStrategy.load(p3)
     assert loaded.comm_kind(0) == "ar" and loaded.chunk_count(0) == 1
+
+
+def test_compile_cache_placement(tmp_path):
+    """The entry points' compile cache: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set (left to JAX), else the fixed ``<checkout>/.jax_cache``."""
+    code = """
+import os, jax
+from repro.launch import train as T
+path = T.use_compile_cache()
+print("CACHE", path, jax.config.jax_compilation_cache_dir)
+"""
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    outs = []
+    for extra in ({}, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)}):
+        proc = subprocess.run([sys.executable, "-c", code],
+                              env=dict(env, **extra), capture_output=True,
+                              text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        outs.append(proc.stdout.split("CACHE ")[1].split())
+    checkout = os.path.abspath(os.path.join(SRC, ".."))
+    assert outs[0] == [os.path.join(checkout, ".jax_cache")] * 2, outs
+    assert outs[1] == [str(tmp_path)] * 2, outs
 
 
 def test_strategy_from_fusion_graph():
@@ -433,7 +469,7 @@ def test_dp_layout_and_zero1():
     compile and train one step equal to the tp layout's loss."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import stacked as ST
 from repro.distributed.train_step import build_train_step, jit_train_step
@@ -446,7 +482,7 @@ params = ST.init_params(key, cfg)
 init, _ = adamw(1e-3)
 opt = init(jax.tree.map(lambda p: p.astype(jnp.float32), params))
 batch = materialize_batch(cfg, 8, 32, seed=0)
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
 losses = {}
 for name, kw in (("tp", {}), ("dp", {"layout": "dp"}),

@@ -164,3 +164,32 @@ def test_worker_pool_matches_serial():
     assert r_par.best_cost == r_ser.best_cost
     assert r_par.simulations == r_ser.simulations
     assert r_par.best.signature() == r_ser.best.signature()
+
+
+def test_worker_pool_imports_no_jax():
+    """The spawn pool's workers price candidates without importing jax, so
+    a search with ``workers > 1`` starts no child that could reach for the
+    process's accelerator.  The parent is a bare ``-c`` interpreter: spawn
+    re-imports only the parent's ``__main__`` besides the pool's own code."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro.core import Simulator\n"
+        "from repro.core.search import _CandidatePool\n"
+        "sys.path.insert(0, 'tests')\n"
+        "from test_incremental_engine import chain_graph\n"
+        "g = chain_graph(n=6, grads=(2, 4))\n"
+        "pool = _CandidatePool(Simulator(n_devices=8), g, workers=2)\n"
+        "assert pool.evaluate([g])[0] > 0\n"
+        "print('JAX_IN_WORKER', pool._ex.submit(\n"
+        "    eval, \"'jax' in __import__('sys').modules\").result())\n"
+        "pool.close()\n")
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "JAX_IN_WORKER False" in proc.stdout, proc.stdout

@@ -1,0 +1,138 @@
+"""Compile-only checks for the TPU, at qwen2-0.5b's published widths.
+
+Nothing here runs on a chip: each test compiles for a *described* v5e:2x2
+topology with the TPU compiler installed beside JAX, which refuses what
+the chip would refuse -- unaligned tiling, too much VMEM, a Mosaic kernel
+it cannot partition, a program that does not fit the device's memory.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may hold the TPU library, and the
+test workers must all collect the same tests.  Keep every such test in
+this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.data.pipeline import make_batch_specs
+from repro.distributed.train_step import (GradSyncStrategy, build_train_step,
+                                          jit_train_step)
+from repro.kernels import bucket_pack as BP
+from repro.kernels import flash_attention as FA
+from repro.kernels import fused_grad_sync as FG
+from repro.models import stacked as ST
+from repro.optim import adamw
+
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.experimental import topologies
+
+    saved = (os.environ.get("TPU_LOG_DIR"),
+             jax.config.jax_enable_compilation_cache)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a TPU entry written here could never be read back without a chip
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", saved[1])
+    if saved[0] is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def qwen():
+    cfg = get_config("qwen2-0.5b")
+    params = jax.eval_shape(
+        lambda: ST.init_params(jax.random.PRNGKey(0), cfg))
+    return cfg, params
+
+
+def _leaf_shapes(params):
+    """The largest and the smallest gradient leaf of the model."""
+    leaves = sorted(jax.tree.leaves(params), key=lambda l: l.size)
+    return [leaves[-1].shape, leaves[0].shape]
+
+
+def _custom_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("kernel", ["convert_copy", "fused_pack",
+                                    "fused_unpack"])
+def test_grad_sync_kernels_compile(kernel, dtype, one_chip, qwen):
+    """The fused-sync staging kernels lower to Mosaic at the model's own
+    largest and smallest leaf sizes."""
+    shapes = _leaf_shapes(qwen[1])
+    sizes = [int(np.prod(s)) for s in shapes]
+    total = sum(sizes)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    if kernel == "convert_copy":
+        out = jnp.float32 if dtype == jnp.bfloat16 else jnp.bfloat16
+        fn = lambda x: BP.convert_copy_kernel(x, out, interpret=False)
+        args = (arg((sizes[0],), dtype),)
+    elif kernel == "fused_pack":
+        fn = lambda ls: FG.fused_pack_kernel(ls, total, 4, 2,
+                                             interpret=False)
+        args = ([arg(s, dtype) for s in shapes],)
+    else:
+        fn = lambda b: FG.fused_unpack_kernel(b, shapes, [dtype] * 2,
+                                              interpret=False)
+        args = (arg((total,), jnp.float32),)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _custom_calls(compiled) >= (1 if kernel == "convert_copy" else 2)
+
+
+def test_flash_attention_compiles(one_chip):
+    """The flash-attention kernel at qwen2-0.5b's head shapes: 14 query
+    heads over 2 KV heads of width 64, sequence 2048."""
+    q = jax.ShapeDtypeStruct((1, 2048, 14, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: FA.flash_attention_kernel(
+        q, k, v, causal=True, interpret=False)).lower(q, kv, kv).compile()
+    assert _custom_calls(compiled) >= 1
+
+
+def test_full_width_train_step_fits_one_chip(topo, qwen):
+    """The whole qwen2-0.5b ``ddp_tp`` train step (24 layers, bf16 params,
+    f32 Adam moments, batch 8 x seq 1024) compiles for one chip and needs
+    less than its 16 GB."""
+    cfg, params = qwen
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    optimizer = adamw(1e-3, weight_decay=0.01)
+    opt = jax.eval_shape(lambda: optimizer[0](jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), params)))
+    specs = make_batch_specs(cfg, 8, 1024)
+    step = build_train_step(cfg, mesh, mode="ddp_tp", optimizer=optimizer,
+                            strategy=GradSyncStrategy.size_capped(params))
+    jf = jit_train_step(step, cfg, mesh, params, opt, specs)
+    ma = jf.lower(params, opt, specs).compile().memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert ma.argument_size_in_bytes > 4 * 10**9  # params + Adam moments
+    assert total < HBM_BYTES, total
