@@ -17,12 +17,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+
 
 def _keystr(path) -> str:
     return jax.tree_util.keystr(path).replace("/", "_")
 
 
 def save_checkpoint(ckpt_dir: str, step: int, tree) -> str:
+    with obs.span("ckpt.save"):
+        return _save(ckpt_dir, step, tree)
+
+
+def _save(ckpt_dir: str, step: int, tree) -> str:
     os.makedirs(ckpt_dir, exist_ok=True)
     leaves_with_paths = jax.tree_util.tree_flatten_with_path(tree)[0]
     arrays = {}

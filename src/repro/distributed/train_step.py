@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import obs
 from ..models import model as M
 from ..models.config import ModelConfig
 from ..optim import adamw, apply_updates, clip_by_global_norm
@@ -183,6 +184,7 @@ def _fused_bucket_sync(leaves, dp: int, chunks: int, dp_axes,
                           [l.dtype for l in leaves]), f32
 
 
+@obs.scope("grad_sync")
 def sync_grads(grads, strategy: GradSyncStrategy, dp_axes: Sequence[str],
                mesh=None, pspecs=None):
     """Explicit bucketed gradient synchronisation (mean) — DisCo tensor
@@ -230,61 +232,65 @@ def sync_grads(grads, strategy: GradSyncStrategy, dp_axes: Sequence[str],
         out: list = [None] * len(leaves_local)
         prev_fused = None
         for bi, bucket in enumerate(strategy.buckets):
-            if strategy.is_fused(bi) and dp > 1:
-                # searched in-kernel fused path: Pallas staging kernels
-                # around a real RS+AG pair
-                outs, packed = _fused_bucket_sync(
-                    [leaves_local[i] for i in bucket], dp,
-                    strategy.chunk_count(bi), dp_axes,
-                    barrier_with=(prev_fused if strategy.barriers
-                                  else None))
-                for i, o in zip(bucket, outs):
-                    out[i] = o
-                prev_fused = packed
-                continue
-            flats = [leaves_local[i].reshape(-1) for i in bucket]
-            fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-            if strategy.barriers and prev_fused is not None:
-                fused, _ = jax.lax.optimization_barrier((fused, prev_fused))
-            # reduce in f32: gradient-accuracy standard practice, and works
-            # around an XLA:CPU bf16 all-reduce miscompile in the dry-run.
-            dt = fused.dtype
-            f32 = fused.astype(jnp.float32)
-            rs_ag = strategy.comm_kind(bi) == "rs_ag" and dp > 1
+            with jax.named_scope(f"bucket_{bi}"):
+                if strategy.is_fused(bi) and dp > 1:
+                    # searched in-kernel fused path: Pallas staging kernels
+                    # around a real RS+AG pair
+                    outs, packed = _fused_bucket_sync(
+                        [leaves_local[i] for i in bucket], dp,
+                        strategy.chunk_count(bi), dp_axes,
+                        barrier_with=(prev_fused if strategy.barriers
+                                      else None))
+                    for i, o in zip(bucket, outs):
+                        out[i] = o
+                    prev_fused = packed
+                    continue
+                flats = [leaves_local[i].reshape(-1) for i in bucket]
+                fused = jnp.concatenate(flats) if len(flats) > 1 \
+                    else flats[0]
+                if strategy.barriers and prev_fused is not None:
+                    fused, _ = jax.lax.optimization_barrier(
+                        (fused, prev_fused))
+                # reduce in f32: gradient-accuracy standard practice, and
+                # works around an XLA:CPU bf16 all-reduce miscompile in the
+                # dry-run.
+                dt = fused.dtype
+                f32 = fused.astype(jnp.float32)
+                rs_ag = strategy.comm_kind(bi) == "rs_ag" and dp > 1
 
-            def reduce_one(part):
-                if rs_ag:
-                    n0 = part.shape[0]
-                    pad = (-n0) % dp
-                    if pad:
-                        part = jnp.concatenate(
-                            [part, jnp.zeros((pad,), jnp.float32)])
-                    shard = jax.lax.psum_scatter(part, tuple(dp_axes),
-                                                 scatter_dimension=0,
-                                                 tiled=True) / dp
-                    part = jax.lax.all_gather(shard, tuple(dp_axes),
-                                              tiled=True)
-                    if pad:
-                        part = part[:n0]
+                def reduce_one(part):
+                    if rs_ag:
+                        n0 = part.shape[0]
+                        pad = (-n0) % dp
+                        if pad:
+                            part = jnp.concatenate(
+                                [part, jnp.zeros((pad,), jnp.float32)])
+                        shard = jax.lax.psum_scatter(part, tuple(dp_axes),
+                                                     scatter_dimension=0,
+                                                     tiled=True) / dp
+                        part = jax.lax.all_gather(shard, tuple(dp_axes),
+                                                  tiled=True)
+                        if pad:
+                            part = part[:n0]
+                    else:
+                        part = jax.lax.psum(part, tuple(dp_axes)) / dp
+                    return part
+
+                k = min(strategy.chunk_count(bi), max(f32.shape[0], 1))
+                if k > 1:
+                    # even byte split; each chunk is its own collective
+                    cuts = [f32.shape[0] * c // k for c in range(k + 1)]
+                    f32 = jnp.concatenate([reduce_one(f32[cuts[c]:cuts[c + 1]])
+                                           for c in range(k)])
                 else:
-                    part = jax.lax.psum(part, tuple(dp_axes)) / dp
-                return part
-
-            k = min(strategy.chunk_count(bi), max(f32.shape[0], 1))
-            if k > 1:
-                # even byte split; each chunk is its own collective
-                cuts = [f32.shape[0] * c // k for c in range(k + 1)]
-                f32 = jnp.concatenate(
-                    [reduce_one(f32[cuts[c]:cuts[c + 1]]) for c in range(k)])
-            else:
-                f32 = reduce_one(f32)
-            fused = f32.astype(dt)
-            prev_fused = fused
-            off = 0
-            for i in bucket:
-                n = leaves_local[i].size
-                out[i] = fused[off:off + n].reshape(leaves_local[i].shape)
-                off += n
+                    f32 = reduce_one(f32)
+                fused = f32.astype(dt)
+                prev_fused = fused
+                off = 0
+                for i in bucket:
+                    n = leaves_local[i].size
+                    out[i] = fused[off:off + n].reshape(leaves_local[i].shape)
+                    off += n
         return tuple(out)
 
     if mesh is None or "model" not in mesh.shape:
@@ -366,6 +372,7 @@ def build_train_step(
             return loss * scale, jax.tree.map(lambda g: g * scale, grads)
         return jax.value_and_grad(local_loss)(params, batch)
 
+    @obs.scope("optimizer")
     def update(params, opt_state, loss, grads):
         grads, gnorm = clip_by_global_norm(grads, clip_norm)
         updates, opt_state = opt_update(grads, opt_state, params)

@@ -18,12 +18,12 @@ set, and in ``<checkout>/.jax_cache`` otherwise (:func:`use_compile_cache`).
 import argparse
 import os
 import pathlib
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .. import plan as RP
 from ..checkpoint import restore_checkpoint, save_checkpoint
 from ..configs import get_config
@@ -57,8 +57,9 @@ def use_compile_cache() -> str:
 def init_params(cfg, seed: int):
     """The model's parameters from ``seed``, initialised on the device in
     one compiled program (the same values on every call)."""
-    return jax.jit(ST.init_params, static_argnums=1)(
-        jax.random.PRNGKey(seed), cfg)
+    with obs.span("train.init_params"):
+        return jax.jit(ST.init_params, static_argnums=1)(
+            jax.random.PRNGKey(seed), cfg)
 
 
 def search_strategy(cfg, params, batch, n_devices: int,
@@ -70,9 +71,13 @@ def search_strategy(cfg, params, batch, n_devices: int,
     def loss(p, bt):
         return ST.loss_fn(p, cfg, bt)
 
-    g = profile_graph(trace_grad_graph(loss, params, batch))
-    plan = RP.compile(graph=g, cluster=cluster, n_devices=n_devices,
-                      unchanged_limit=unchanged_limit, seed=seed)
+    with obs.span("plan.trace"):
+        g = trace_grad_graph(loss, params, batch)
+    with obs.span("plan.profile"):
+        g = profile_graph(g)
+    with obs.span("plan.search"):
+        plan = RP.compile(graph=g, cluster=cluster, n_devices=n_devices,
+                          unchanged_limit=unchanged_limit, seed=seed)
     return plan.grad_sync(params), plan
 
 
@@ -89,16 +94,16 @@ def choose_strategy(kind: str, cfg, params, local_batch, dp: int, *,
         print(f"loaded strategy: {len(strat.buckets)} buckets")
         return strat, plan
     if kind == "auto":
-        t0 = time.perf_counter()
-        strat, plan = search_strategy(cfg, params, local_batch, n_devices=dp,
-                                      cluster=cluster)
+        with obs.span("train.choose_strategy") as sp:
+            strat, plan = search_strategy(cfg, params, local_batch,
+                                          n_devices=dp, cluster=cluster)
         prov = plan.provenance
         print(f"DisCo search: {prov['initial_cost'] * 1e6:.1f} -> "
               f"{prov['best_cost'] * 1e6:.1f} us simulated; "
               f"plan buckets {len(strat.buckets)} over "
               f"{prov['grad_tensors']} gradient tensors; search "
               f"simulations {prov['simulations']}; search seconds "
-              f"{time.perf_counter() - t0:.3f}")
+              f"{sp.seconds:.3f}")
         return strat, plan
     if kind == "ddp":
         return GradSyncStrategy.size_capped(params), None
@@ -111,24 +116,28 @@ def choose_strategy(kind: str, cfg, params, local_batch, dp: int, *,
 
 def compile_step(cfg, mesh, strat, params, opt, batch, optimizer):
     """Build, jit and AOT-compile the ``ddp_tp`` train step for ``batch``'s
-    shapes.  Returns (compiled step, compile seconds)."""
+    shapes.  Returns (compiled step, seconds of its lowering and
+    compile)."""
     step_fn = build_train_step(cfg, mesh, mode="ddp_tp", strategy=strat,
                                optimizer=optimizer, remat=True)
     specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
              for k, v in batch.items()}
     jf = jit_train_step(step_fn, cfg, mesh, params, opt, specs)
-    t0 = time.perf_counter()
-    compiled = jf.lower(params, opt, specs).compile()
-    return compiled, time.perf_counter() - t0
+    with obs.span("train.lower") as lo:
+        lowered = jf.lower(params, opt, specs)
+    with obs.span("train.compile") as co:
+        compiled = lowered.compile()
+    return compiled, lo.seconds + co.seconds
 
 
 def step_batch(example: dict, ds: SyntheticLMDataset, step: int,
                vocab: int) -> dict:
     """The global batch of ``step``: fresh synthetic tokens, the rest of
-    ``example`` (stubbed modality inputs) unchanged."""
-    batch = dict(example)
-    batch["tokens"] = jnp.asarray(ds.global_step_batch(step) % vocab)
-    return batch
+    ``example`` (stubbed modality inputs) unchanged, on the device."""
+    with obs.span("train.step_batch"):
+        batch = dict(example)
+        batch["tokens"] = jnp.asarray(ds.global_step_batch(step) % vocab)
+        return batch
 
 
 def parse_args(argv=None):
@@ -163,8 +172,20 @@ def parse_args(argv=None):
 
 def main(argv=None) -> dict:
     """Run the driver; returns its ``losses`` and per-step ``step_ms``
-    (each step ends in ``block_until_ready``)."""
+    (the ``train.dispatch`` and ``train.wait`` spans of the step, which
+    ends in ``block_until_ready``).
+
+    Each step runs under ``StepTraceAnnotation("train", step_num=i)`` and
+    the spans ``train.step_batch``, ``train.dispatch``, ``train.wait`` and
+    ``train.loss`` (``repro.obs``).  Every ``--log-every`` steps the log
+    line gives, besides loss and step time, each span's mean milliseconds
+    and calls and the ``obs`` counters (compilation cache hits and misses)
+    since the last line, the first line since the start, set-up included:
+    the operator's view of where the host's time went.  For a timeline,
+    run under ``jax.profiler``: the spans land on its host plane, on the
+    device ops' clock."""
     args = parse_args(argv)
+    last = obs.snapshot()
     use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
@@ -207,15 +228,22 @@ def main(argv=None) -> dict:
 
     losses, step_ms = [], []
     for i in range(start, args.steps):
-        batch = step_batch(example, ds, i, cfg.vocab)
-        t0 = time.perf_counter()
-        params, opt, metrics = jax.block_until_ready(step(params, opt, batch))
-        step_ms.append((time.perf_counter() - t0) * 1e3)
-        losses.append(float(metrics["loss"]))
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            batch = step_batch(example, ds, i, cfg.vocab)
+            with obs.span("train.dispatch") as sent:
+                out = step(params, opt, batch)
+            with obs.span("train.wait") as waited:
+                params, opt, metrics = jax.block_until_ready(out)
+            with obs.span("train.loss"):
+                losses.append(float(metrics["loss"]))
+        step_ms.append((sent.seconds + waited.seconds) * 1e3)
         if i % args.log_every == 0 or i == args.steps - 1:
+            now = obs.snapshot()
             print(f"step {i:5d}  loss {losses[-1]:.4f}  "
                   f"gnorm {float(metrics['grad_norm']):.3f}  "
-                  f"{step_ms[-1]:.1f} ms/step")
+                  f"{step_ms[-1]:.1f} ms/step  | "
+                  f"{obs.line(obs.delta(now, last))}")
+            last = now
         if args.ckpt_dir and i > start and i % args.ckpt_every == 0:
             save_checkpoint(args.ckpt_dir, i, (params, opt))
     if args.ckpt_dir:

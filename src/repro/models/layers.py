@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .config import ModelConfig
 
 Axis = jax.sharding.PartitionSpec  # alias used by sharding rules
@@ -199,6 +200,7 @@ def _flash_xla(q, k, v, causal, window, qb: int = _Q_BLOCK, kb: int = _Q_BLOCK):
     return outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, S, H, hd)
 
 
+@obs.scope("attention")
 def attention_fwd(p: dict, cfg: ModelConfig, x, positions, *,
                   cache: Optional[dict] = None, pos: Optional[jnp.ndarray] = None,
                   window: Optional[int] = None, use_flash: bool = False,
@@ -327,6 +329,7 @@ def _rms(x, scale):
     return (x.astype(jnp.float32) * jax.lax.rsqrt(ms + 1e-6) * scale).astype(x.dtype)
 
 
+@obs.scope("attention")
 def mla_fwd(p: dict, cfg: ModelConfig, x, positions, *,
             cache: Optional[dict] = None, pos=None,
             return_cache: bool = False, cache_len: int = 0):
@@ -423,6 +426,7 @@ def _act(cfg: ModelConfig, x):
     return jax.nn.relu(x)
 
 
+@obs.scope("mlp")
 def mlp_fwd(p: dict, cfg: ModelConfig, x):
     up = x @ p["w_up"].astype(x.dtype)
     h = _act(cfg, x @ p["w_gate"].astype(x.dtype)) * up if cfg.glu else _act(cfg, up)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .config import ModelConfig
 from . import layers as L
 from . import model as M
@@ -200,6 +201,7 @@ def encode(params, cfg: ModelConfig, frames):
 _CE_CHUNK = 512
 
 
+@obs.scope("embed")
 def _embed_maybe_vp(params, cfg: ModelConfig, tokens, vp_mesh):
     from . import vocab_parallel as VP
 
@@ -287,7 +289,9 @@ def loss_fn(params, cfg: ModelConfig, batch, *, use_kernels: bool = False,
           targets.reshape(B, nc, chunk).transpose(1, 0, 2),
           weights.reshape(B, nc, chunk).transpose(1, 0, 2))
     body = jax.checkpoint(ce_chunk) if remat else ce_chunk
-    (ce_sum, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())), xs)
+    with jax.named_scope("head"):
+        (ce_sum, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())),
+                                        xs)
     return ce_sum / jnp.maximum(cnt, 1.0) + aux
 
 

@@ -231,18 +231,6 @@ class ServeEngine:
                 raise RuntimeError("serve loop did not converge")
         return self.completed
 
-    def stats(self) -> dict:
-        lat = [r.latency for r in self.completed if r.latency is not None]
-        ttft = [r.ttft for r in self.completed if r.ttft is not None]
-        toks = sum(len(r.output) for r in self.completed)
-        return {
-            "completed": len(self.completed),
-            "decode_steps": self._steps,
-            "tokens": toks,
-            "mean_latency_s": float(np.mean(lat)) if lat else 0.0,
-            "mean_ttft_s": float(np.mean(ttft)) if ttft else 0.0,
-        }
-
     def metrics(self) -> dict:
         """Per-request latency summary over the completed set: TTFT /
         TPOT / end-to-end latency percentiles plus the aggregate decode

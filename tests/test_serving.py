@@ -61,11 +61,12 @@ def test_engine_slot_reuse(setup):
                                np.int32),
                            max_new_tokens=4))
     done = eng.run_to_completion()
-    s = eng.stats()
+    s = eng.metrics()
     assert s["completed"] == 5
     assert s["tokens"] == 5 * 4
     # with 2 slots and 5 requests of 4 tokens, decode steps must exceed 4
     assert s["decode_steps"] >= 8
+    assert 0 < s["mean_ttft_s"] <= s["mean_latency_s"]
 
 
 def test_engine_eos_stops(setup):
