@@ -1,7 +1,8 @@
-"""Mean host milliseconds of the program's ``train.step_batch`` span (make
-the step's batch and put it on the device) over the run's calls: the
-checked steps and the window.  Read from ``repro.obs`` in this process;
-nothing where the program has no such span."""
+"""Mean host milliseconds per step of the program's ``train.step_batch``
+span (make the step's batch and put it on the device) in the window: the
+last ``rec["window"]["steps"]`` calls, since nothing after the window makes
+a batch.  Read from ``repro.obs`` in this process; nothing where the
+program has no such span."""
 
 
 def read(rec):
@@ -9,5 +10,6 @@ def read(rec):
         from repro import obs
     except ImportError:
         return None
-    s = obs.snapshot()["spans"].get("train.step_batch")
-    return s["total_s"] / s["count"] * 1e3 if s else None
+    n = rec.get("window", {}).get("steps")
+    calls = obs.recent("train.step_batch")[-n:] if n else []
+    return sum(s for _, s in calls) / len(calls) * 1e3 if calls else None

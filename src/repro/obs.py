@@ -10,12 +10,15 @@ listeners count JAX's persistent compilation cache hits and misses, time
 each backend compile (span ``jax.compile``, a cache load included), and a
 ``gc.callbacks`` hook records Python's collections as span ``python.gc``.
 ``snapshot()`` returns the aggregates, ``delta`` the part of one snapshot
-since another and ``line`` a log line of it; ``reset()`` clears them.
+since another and ``line`` a log line of it; ``recent(name)`` the last
+``RECENT`` calls or counts of a name with the time each ended, for a
+reading over one stretch of a run; ``reset()`` clears them.
 Nothing is written out: when a timeline is wanted, the profiler's trace
 is it.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import gc
 import threading
@@ -29,6 +32,14 @@ _GC = "python.gc"
 # always there, so that a collection inside snapshot() adds no key
 _SPANS: dict = {_GC: [0, 0.0, 0.0]}
 _COUNTS: dict = {}
+RECENT = 256
+# name -> its last RECENT (perf_counter_ns at the end, seconds or count)
+_RECENT: dict = {_GC: collections.deque(maxlen=RECENT)}
+
+
+def _note(name: str, value) -> None:
+    _RECENT.setdefault(name, collections.deque(maxlen=RECENT)).append(
+        (time.perf_counter_ns(), value))
 
 
 def _add(name: str, seconds: float) -> None:
@@ -37,11 +48,13 @@ def _add(name: str, seconds: float) -> None:
         agg[0] += 1
         agg[1] += seconds
         agg[2] = max(agg[2], seconds)
+        _note(name, seconds)
 
 
 def count(name: str, n: int = 1) -> None:
     with _LOCK:
         _COUNTS[name] = _COUNTS.get(name, 0) + n
+        _note(name, n)
 
 
 class span:
@@ -84,6 +97,13 @@ def snapshot() -> dict:
                 "counts": dict(_COUNTS)}
 
 
+def recent(name: str) -> list:
+    """The last ``RECENT`` ``(perf_counter_ns at the end, seconds or n)``
+    of span or counter ``name``, oldest first."""
+    with _LOCK:
+        return list(_RECENT.get(name, ()))
+
+
 def delta(new: dict, old: dict) -> dict:
     """Counts and seconds of ``new`` less those of ``old`` (``max_s`` left
     out), for the names that moved."""
@@ -112,6 +132,8 @@ def reset() -> None:
         _SPANS.clear()
         _SPANS[_GC] = [0, 0.0, 0.0]
         _COUNTS.clear()
+        _RECENT.clear()
+        _RECENT[_GC] = collections.deque(maxlen=RECENT)
 
 
 _EVENTS = {"/jax/compilation_cache/cache_hits": "compile_cache.hits",

@@ -51,20 +51,34 @@ class Tokens:
 
 
 def test_program_span_and_counter_readers():
+    """Set-up makes two batches and two programs, one loaded from the
+    cache; the window makes three batches; the reference then compiles two
+    more.  ``input_ms`` reads the window's batches, ``cache_misses`` set-up's
+    programs."""
     from repro import obs
     from repro.launch import train as T
 
     obs.reset()
     try:
-        for i in range(4):
+        for i in range(2):
             T.step_batch({}, Tokens(), i, 5)
         obs._add("jax.compile", 1.0)
         obs._add("jax.compile", 1.0)
         obs.count("compile_cache.hits")
-        span = obs.snapshot()["spans"]["train.step_batch"]
-        assert spec.metric_reader(BENCH_DIR, "input_ms")({}) == \
-            pytest.approx(span["total_s"] / 4 * 1e3)
-        assert spec.metric_reader(BENCH_DIR, "cache_misses")({}) == 1.0
+        for i in range(2, 5):
+            T.step_batch({}, Tokens(), i, 5)
+        window = [s for _, s in obs.recent("train.step_batch")[-3:]]
+        obs._add("jax.compile", 1.0)
+        obs._add("jax.compile", 1.0)
+        rec = {"window": {"steps": 3, "seconds": 1.0, "tokens": 24}}
+        assert spec.metric_reader(BENCH_DIR, "input_ms")(rec) == \
+            pytest.approx(sum(window) / 3 * 1e3)
+        assert spec.metric_reader(BENCH_DIR, "cache_misses")(rec) == 1.0
+        for name in ("input_ms", "cache_misses"):
+            assert spec.metric_reader(BENCH_DIR, name)({}) is None
+        for _ in range(obs.RECENT):     # set-up's count is then unknown
+            obs._add("jax.compile", 1.0)
+        assert spec.metric_reader(BENCH_DIR, "cache_misses")(rec) is None
     finally:
         obs.reset()
 

@@ -57,6 +57,17 @@ def test_delta_and_log_line(fresh):
     assert obs.line(obs.delta(old, old)) == "no spans"
 
 
+def test_recent_keeps_the_last_calls_in_order(fresh):
+    for i in range(obs.RECENT + 3):
+        obs._add("s", float(i))
+    obs.count("c", 2)
+    got = obs.recent("s")
+    assert [v for _, v in got] == [float(i) for i in range(3, obs.RECENT + 3)]
+    assert all(a[0] <= b[0] for a, b in zip(got, got[1:]))
+    assert [v for _, v in obs.recent("c")] == [2]
+    assert obs.recent("none") == []
+
+
 def test_span_closes_on_error(fresh):
     with pytest.raises(ValueError):
         with obs.span("fails"):
