@@ -117,9 +117,23 @@ def _causal_bias(S, T, causal, window, q_offset=0):
 
 
 # Above this query length the XLA path chunks queries to bound the softmax
-# working set (the Pallas flash kernel is the TPU runtime fast path).
+# working set (on the TPU causal attention takes the kernel instead).
 _CHUNK_THRESHOLD = 2048
 _Q_BLOCK = 512
+
+
+def takes_kernel(mask_given: bool, causal: bool, window: Optional[int],
+                 S: int, T: int, platform: str) -> bool:
+    """Whether ``sdpa`` runs through the block-skipping causal kernel
+    (``kernels.ops.causal_attention``): causal self-attention over a whole
+    sequence of a block multiple, with no mask or window given, on the TPU.
+    Decode against a cache, cross-attention, encoders, sliding windows and
+    the CPU keep the XLA paths."""
+    if (platform != "tpu" or mask_given or not causal or window is not None
+            or S != T):
+        return False
+    from ..kernels import ops as kops  # Pallas loads only where it runs
+    return kops.causal_block(S) is not None
 
 
 def sdpa(q, k, v, mask, use_flash: bool = False, window: Optional[int] = None,
@@ -127,15 +141,25 @@ def sdpa(q, k, v, mask, use_flash: bool = False, window: Optional[int] = None,
     """q: (B,S,H,hd); k,v: (B,T,KV,hd); mask: (B,1,1|S,T) additive or None.
 
     GQA: query heads grouped over KV heads.  ``use_flash`` routes to the
-    Pallas kernel when the mask is the standard causal(+window) one;
-    otherwise long sequences take a query-chunked XLA path so the score
-    matrix working set stays bounded.
+    forward-only Pallas kernel when the mask is the standard
+    causal(+window) one; on the TPU causal self-attention takes the
+    splash kernel (``takes_kernel``); otherwise long sequences take a
+    query-chunked XLA path so the score matrix working set stays bounded.
+    Each call counts ``attention.kernel`` or ``attention.xla`` in
+    ``repro.obs`` as it is traced.
     """
     B, S, H, hd = q.shape
     T = k.shape[1]
     if use_flash and mask is None:
         from ..kernels import ops as kops
+        obs.count("attention.kernel")
         return kops.flash_attention(q, k, v, causal=causal, window=window)
+    if takes_kernel(mask is not None, causal, window, S, T,
+                    jax.default_backend()):
+        from ..kernels import ops as kops
+        obs.count("attention.kernel")
+        return kops.causal_attention(q, k, v)
+    obs.count("attention.xla")
     if mask is None:
         if S > _CHUNK_THRESHOLD and S == T:
             for blk in (_Q_BLOCK, 256, 128, 64):
@@ -151,8 +175,8 @@ def _flash_xla(q, k, v, causal, window, qb: int = _Q_BLOCK, kb: int = _Q_BLOCK):
     """Online-softmax attention in plain XLA (double lax.scan over query and
     KV blocks).  Working set per step is (B,H,qb,kb) — the flash-attention
     recurrence, so 32k/500k contexts lower with bounded temps.  Causality is
-    enforced by masking (blocks are not skipped — the Pallas kernel is the
-    block-skipping fast path on real TPUs)."""
+    enforced by masking (blocks are not skipped — ``takes_kernel`` sends
+    causal attention on the TPU to the block-skipping kernel)."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV

@@ -144,3 +144,100 @@ def test_flash_kernel_inside_model():
     l_ker, _ = ST.forward(params, cfg, toks, use_kernels=True)
     np.testing.assert_allclose(np.asarray(l_ker), np.asarray(l_ref),
                                rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("S", [256, 512])
+@pytest.mark.parametrize("H,KV", [(14, 2), (4, 4)])
+def test_causal_attention_kernel(H, KV, S):
+    """The splash wrapper (interpreted here) at the rule's blocks of 128
+    with the fused backward, so whole blocks above the diagonal are
+    skipped: output and the gradients of q, k and v against the dense XLA
+    path in bf16 and the oracle in f32."""
+    from repro.models import layers as L
+
+    hd = 64
+    q, k, v = arr((2, S, H, hd)), arr((2, S, KV, hd)), arr((2, S, KV, hd))
+    ct = arr((2, S, H, hd))
+    blocks = K.causal_block(256)
+    assert blocks.block_q == blocks.block_kv_dkv == 128
+    kern = lambda q, k, v: K.causal_attention(q, k, v, blocks)
+    dense = lambda q, k, v: L._sdpa_dense(
+        q, k, v, L._causal_bias(S, S, True, None)[None, None, None])
+    ref = lambda q, k, v: K.flash_attention_ref(q, k, v, causal=True)
+
+    def out_and_grads(fn, dt):
+        args = [x.astype(dt) for x in (q, k, v)]
+        loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct)
+        return [np.asarray(x, np.float32) for x in
+                (jax.jit(fn)(*args),
+                 *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*args))]
+
+    for got, want, dt in ((kern, dense, jnp.bfloat16),
+                          (kern, ref, jnp.float32)):
+        for g, w in zip(out_and_grads(got, dt), out_and_grads(want, dt)):
+            scale = max(1.0, float(np.abs(w).max()))
+            np.testing.assert_allclose(g / scale, w / scale, **tol(dt))
+
+
+@pytest.mark.parametrize("mask_given,causal,window,S,T,platform,takes", [
+    (False, True, None, 1024, 1024, "tpu", True),    # training, prefill
+    (False, True, None, 4096, 4096, "tpu", True),
+    (False, True, None, 1024, 1024, "cpu", False),   # the CPU
+    (False, True, None, 1024, 1024, "gpu", False),
+    (True, True, None, 1, 1024, "tpu", False),       # decode, cache mask
+    (True, True, None, 1024, 1024, "tpu", False),    # a given mask
+    (False, False, None, 1024, 1024, "tpu", False),  # encoder
+    (False, False, None, 64, 1024, "tpu", False),    # cross-attention
+    (False, True, 512, 1024, 1024, "tpu", False),    # sliding window
+    (False, True, None, 1000, 1000, "tpu", False),   # no block divides
+    (False, True, None, 64, 64, "tpu", False),
+])
+def test_attention_kernel_route(mask_given, causal, window, S, T, platform,
+                                takes):
+    from repro.models import layers as L
+
+    assert L.takes_kernel(mask_given, causal, window, S, T,
+                          platform) is takes
+
+
+@pytest.mark.parametrize("arch,in_mesh", [("qwen2-0.5b", False),
+                                          ("qwen2-0.5b", True),
+                                          ("deepseek-v2-lite-16b", False)])
+def test_causal_kernel_inside_model(arch, in_mesh, monkeypatch):
+    """A reduced model's loss and gradients with ``sdpa`` routed as on the
+    TPU (the kernel interpreted here) match the XLA path: GQA, and MLA's
+    call with v padded to the query width; in the trainer's ``shard_map``
+    over ``data`` with ``model`` left automatic, the kernel's own nested
+    ``shard_map`` places it."""
+    from jax.sharding import AxisType, Mesh, PartitionSpec as P
+    from repro import obs
+    from repro.configs import get_config
+    from repro.models import layers as L
+    from repro.models import stacked as ST
+
+    cfg = get_config(arch).reduced()
+    params = ST.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 128),
+                                          0, cfg.vocab)}
+    def step():  # a fresh function, traced anew under each route
+        grad = jax.value_and_grad(
+            lambda p, b: ST.loss_fn(p, cfg, b, remat=True))
+        if not in_mesh:
+            return jax.jit(grad)
+        mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        return jax.jit(jax.shard_map(grad, mesh=mesh,
+                                     in_specs=(P(), P("data")),
+                                     out_specs=P(), axis_names={"data"},
+                                     check_vma=False))
+
+    want = step()(params, batch)
+    before = obs.snapshot()["counts"].get("attention.kernel", 0)
+    route = L.takes_kernel
+    monkeypatch.setattr(L, "takes_kernel",
+                        lambda *a: route(*a[:-1], "tpu"))
+    got = step()(params, batch)
+    assert obs.snapshot()["counts"]["attention.kernel"] > before
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import AxisType, Mesh, SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.data.pipeline import make_batch_specs
@@ -25,6 +26,8 @@ from repro.distributed.train_step import (GradSyncStrategy, build_train_step,
 from repro.kernels import bucket_pack as BP
 from repro.kernels import flash_attention as FA
 from repro.kernels import fused_grad_sync as FG
+from repro.kernels import ops as K
+from repro.models import layers as L
 from repro.models import stacked as ST
 from repro.optim import adamw
 
@@ -135,4 +138,74 @@ def test_full_width_train_step_fits_one_chip(topo, qwen):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert ma.argument_size_in_bytes > 4 * 10**9  # params + Adam moments
+    assert total < HBM_BYTES, total
+
+
+def _attention_grads(q, k, v):
+    """Forward and backward of the causal kernel wrapper."""
+    return jax.grad(lambda q, k, v: jnp.sum(
+        K.causal_attention(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("B,S,H,KV", [(2, 4096, 32, 32),   # stablelm, 1 chip
+                                      (8, 1024, 14, 2)])   # qwen2, per chip
+def test_causal_attention_compiles(B, S, H, KV, one_chip, monkeypatch):
+    """The splash wrapper's forward and fused dq/dkv backward kernels lower
+    to Mosaic at both benchmark cells' per-chip attention shapes (heads of
+    64)."""
+    monkeypatch.setattr(K, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((B, S, H, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, KV, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(_attention_grads).lower(q, kv, kv).compile()
+    assert _custom_calls(compiled) >= 2
+
+
+@pytest.mark.parametrize("dp,tp", [(4, 1), (2, 2)])
+def test_causal_attention_partitions_in_data_shard_map(dp, tp, topo,
+                                                       monkeypatch):
+    """Inside a ``shard_map`` over ``data`` with ``model`` left automatic,
+    as ``build_train_step`` runs the model, the wrapper's nested
+    ``shard_map`` places the Mosaic calls: qwen2-0.5b's attention at 8 x
+    1024 a chip, data-parallel over the four chips, or over two with the
+    heads split over two more."""
+    monkeypatch.setattr(K, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(dp, tp), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    heads = NamedSharding(mesh, P("data", None, "model"))
+    q = jax.ShapeDtypeStruct((8 * dp, 1024, 14, 64), jnp.bfloat16,
+                             sharding=heads)
+    kv = jax.ShapeDtypeStruct((8 * dp, 1024, 2, 64), jnp.bfloat16,
+                              sharding=heads)
+    step = jax.shard_map(_attention_grads, mesh=mesh,
+                         in_specs=(P("data"),) * 3, out_specs=(P("data"),) * 3,
+                         axis_names={"data"}, check_vma=False)
+    compiled = jax.jit(step).lower(q, kv, kv).compile()
+    assert _custom_calls(compiled) >= 2
+
+
+def test_full_width_train_step_with_kernel_fits_one_chip(topo, qwen,
+                                                         monkeypatch):
+    """The qwen2-0.5b step of ``test_full_width_train_step_fits_one_chip``
+    with attention routed as on the TPU: its 24 layers' kernels partition
+    and the step still needs less than one chip's 16 GB."""
+    cfg, params = qwen
+    monkeypatch.setattr(K, "_interpret", lambda: False)
+    route = L.takes_kernel
+    monkeypatch.setattr(L, "takes_kernel", lambda *a: route(*a[:-1], "tpu"))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    optimizer = adamw(1e-3, weight_decay=0.01)
+    opt = jax.eval_shape(lambda: optimizer[0](jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), params)))
+    specs = make_batch_specs(cfg, 8, 1024)
+    step = build_train_step(cfg, mesh, mode="ddp_tp", optimizer=optimizer,
+                            strategy=GradSyncStrategy.size_capped(params))
+    jf = jit_train_step(step, cfg, mesh, params, opt, specs)
+    compiled = jf.lower(params, opt, specs).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert _custom_calls(compiled) >= 3
     assert total < HBM_BYTES, total
