@@ -41,7 +41,7 @@ def readings(cell: dict, seed: int, devices, kinds=None) -> list:
     job, m = cell["traffic"], cell["config"]["model"]
     pool = TokenPool(job, m["vocab"], job["batch_per_chip"] * cell["chips"],
                      seed)
-    names = reference.leaf_names(m)
+    names = reference.leaf_names(cell["reference"], m)
     ref = harness.reference_run(cell, seed, pool, devices)
     out = []
     for kind, rows, numerics in faults(cell):

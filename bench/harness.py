@@ -98,7 +98,6 @@ def set_up(cell: dict, seed: int, devices) -> dict:
     from repro.launch import train as T
     from repro.launch.dryrun import parse_collectives
     from repro.launch.mesh import make_mesh
-    from repro.models.config import ModelConfig
     from repro.optim import adamw, linear_warmup_cosine
 
     job = cell["traffic"]
@@ -108,7 +107,7 @@ def set_up(cell: dict, seed: int, devices) -> dict:
         raise ValueError("the job's clip_norm is not the trainer's")
     rec = {"chips": chips}
     log(f"compile cache {T.use_compile_cache()}")
-    cfg = ModelConfig(**cell["config"]["model"])
+    cfg = spec.model_config(cell["config"]["model"])
     mesh = make_mesh((chips, 1), ("data", "model"), devices=devices)
     params = T.init_params(cfg, seed)
     names = [jax.tree_util.keystr(p) for p, _ in
@@ -188,14 +187,15 @@ def drive(st: dict, cell: dict, seconds: float, traced: bool) -> list:
 
 def reference_run(cell: dict, seed: int, pool, devices, rows=None,
                   numerics: str = "f32") -> dict:
-    """The plain reference over the checked steps' batches (the first
-    ``rows`` rows of each, where given), on the cell's devices."""
+    """The cell's reference family over the checked steps' batches (the
+    first ``rows`` rows of each, where given), on the cell's devices."""
     job, m = cell["traffic"], cell["config"]["model"]
     batches = [pool.global_step_batch(i)[:rows] % m["vocab"]
                for i in range(job["checked_steps"])]
     t0 = time.perf_counter()
-    ref = reference.train(m, job["optimizer"], seed, batches,
-                          job["reference_rows_per_block"], numerics, devices)
+    ref = reference.train(cell["reference"], m, job["optimizer"], seed,
+                          batches, job["reference_rows_per_block"], numerics,
+                          devices)
     log(f"reference ({numerics}, rows {rows}) "
         f"{time.perf_counter() - t0:.3f} s, steps {ref['seconds']}; losses "
         f"{ref['loss']}; grad norms {ref['grad_norm']}")
@@ -204,7 +204,8 @@ def reference_run(cell: dict, seed: int, pool, devices, rows=None,
 
 def compare(cell: dict, names: list, got: dict, ref: dict) -> dict:
     """``check.readings`` where the parameter trees agree leaf for leaf."""
-    if names != reference.leaf_names(cell["config"]["model"]):
+    if names != reference.leaf_names(cell["reference"],
+                                     cell["config"]["model"]):
         return {k: (math.inf, "leaves differ") for k in check.NUMBERS}
     keep = check.moving_channels(ref)
     pairs = {"grad1": (got["grad1"], ref["grad1"]),
@@ -236,7 +237,7 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool, devices,
     stats = [d.memory_stats() or {} for d in devices]
     rec["peak_hbm_bytes"] = max(s.get("peak_bytes_in_use", 0) for s in stats)
     kind = devices[0].device_kind
-    rec["flops_per_token"] = flops.flops_per_token(
+    rec["flops_per_token"] = cell["reference"].flops_per_token(
         cell["config"]["model"], cell["traffic"]["seq_len"])
     if devices[0].platform == "tpu":
         rec["peak_flops_per_s"] = flops.peaks(kind)["bf16_flops_per_s"]

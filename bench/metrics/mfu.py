@@ -1,6 +1,6 @@
 """Model FLOP/s utilization of the whole step: model operations per token
-(``flops.flops_per_token``) times the traced window's tokens per second,
-over the chips' peak from ``peaks.json``."""
+(the reference family's ``flops_per_token``) times the traced window's
+tokens per second, over the chips' peak from ``peaks.json``."""
 
 
 def read(rec):

@@ -1,33 +1,50 @@
-"""Plain float32 reference of the dense decoder and its AdamW training step.
+"""Plain float32 reference of a training step: what every family of model
+shares.
 
-Imports nothing of the program under test.  It restates, in straightforward
-``jax.numpy`` at ``Precision.HIGHEST``:
+Imports nothing of the program under test.  A configuration file names its
+family (``"reference": "<family>"``), and ``bench/references/<family>.py``
+restates what depends on the architecture, in straightforward
+``jax.numpy`` at ``Precision.HIGHEST``.  A family module defines:
 
-* the weight recipe (the same ``jax.random`` draws the program's initialiser
-  makes from the seed), so that it never takes weights from the program;
-* the forward pass: token embedding, pre-norm blocks (RMSNorm or LayerNorm,
-  attention with GQA or full MHA, optional QKV bias, partial rotary, SwiGLU
-  MLP), a final norm and a tied or untied head, with the next-token cross
-  entropy over the first ``S - 1`` positions;
-* gradients by ``jax.grad``, clipping by global norm, AdamW, and the
-  rounding of each updated weight to the dtype the configuration stores.
+    check_supported(m)            raise where the family does not cover ``m``
+    init(key, m)                  float32 weights holding the values the
+                                  program's initialiser stores from ``key``:
+                                  the same ``jax.random`` draws, rounded to
+                                  the storage dtypes, so that the reference
+                                  never takes weights from the program
+    storage_dtypes(m)             the dtype each weight is kept in, a tree
+                                  shaped like the weights
+    mean_loss(params, m, blocks, numerics)
+                                  the training loss of the rows of ``blocks``
+                                  (n, rows, S), one block of rows at a time,
+                                  with whatever the program adds to it
+    flops_per_token(m, seq_len)   model operations a token needs, forward and
+                                  backward (``bench/flops.py`` says which)
 
-Computed in blocks of rows, layer by layer and in query and token chunks,
-so that a full-size step fits on one chip beside nothing else.
+``m`` is the configuration file's ``model`` object.  A jitted function gets
+it as a static argument (``freeze``) and sees it again as a dict
+(``thaw``): a nested block such as ``m["moe"]`` as a dict, a list as a
+tuple.  ``spec.reference_family`` finds a family by its name.
 
-Departures from the published model descriptions, each shared with the
-program and so restated here on purpose:
+This module holds the rest: gradients by ``jax.grad``, clipping by global
+norm, AdamW folded from the past gradients, the rounding of each updated
+weight to the dtype the family stores it in, and the per-leaf and
+per-channel norms that the comparison reads.  It computes in blocks of rows
+(a family's ``mean_loss``), so that a full-size step fits on one chip beside
+nothing else.
 
-* a tied embedding is scaled by ``sqrt(d_model)`` on the way in (Qwen2
-  publishes no such scale);
+The helpers a family imports rather than copies: ``_mm`` (a matmul at
+HIGHEST, or on float8 operands), ``_round_to``, ``_dense`` and
+``_norm_init`` (weight draws), ``_norm``, ``_rope``, ``_attention`` and
+``_next_token_ce``.  Departures from the published model descriptions that
+they restate, each shared with the program and so restated on purpose:
+
 * rotary embedding rotates interleaved pairs ``(x[2i], x[2i+1])`` of the
   first ``rope_frac * head_dim`` channels; Hugging Face rotates the two
   halves.  The two differ by a fixed permutation of the query and key
   projection columns, which random weights do not see;
 * the norm epsilons are 1e-6 (RMSNorm) and 1e-5 (LayerNorm), whatever the
-  published configuration gives;
-* the final norm's weights are kept in float32, every other weight in the
-  configuration's dtype.
+  published configuration gives.
 
 ``numerics="fp8"`` rounds every matmul operand to an 8-bit float (e4m3,
 subnormals flushed) under a per-tensor scale: the control that a
@@ -50,21 +67,28 @@ FP8_MAX = 240.0    # largest finite e4m3 that reduce_precision(4, 3) keeps
 
 
 # ------------------------------------------------------------------ config
-def head_dim(m: dict) -> int:
-    return m["head_dim"] or m["d_model"] // m["n_heads"]
+class _Block(tuple):
+    """A nested block of settings, frozen as its sorted ``(key, value)``
+    pairs."""
 
 
-def check_supported(m: dict) -> None:
-    """The reference covers dense attention decoders only."""
-    for key, ok in (("block", "attn"), ("window", None), ("mla", None),
-                    ("moe", None), ("encdec", None), ("recurrent", None),
-                    ("vlm_prefix_len", 0)):
-        if m.get(key, ok) != ok:
-            raise ValueError(f"reference does not cover {key}={m[key]!r}")
-    if m["act"] != "silu" or not m["glu"]:
-        raise ValueError("reference covers the SwiGLU MLP only")
-    if m["rope_frac"] <= 0.0:
-        raise ValueError("reference covers rotary positions only")
+def freeze(m: dict):
+    """``m`` as a hashable static argument: sorted ``(key, value)`` pairs, a
+    nested dict as a ``_Block`` of its own, a list as a tuple."""
+    if isinstance(m, dict):
+        return _Block(sorted((k, freeze(v)) for k, v in m.items()))
+    if isinstance(m, (list, tuple)):
+        return tuple(freeze(v) for v in m)
+    return m
+
+
+def thaw(items):
+    """The dict that ``freeze`` made ``items`` from, lists as tuples."""
+    if isinstance(items, _Block):
+        return {k: thaw(v) for k, v in items}
+    if isinstance(items, tuple):
+        return tuple(thaw(v) for v in items)
+    return items
 
 
 # ----------------------------------------------------------------- weights
@@ -80,34 +104,6 @@ def _norm_init(m: dict) -> dict:
     return p
 
 
-def _layer_init(key, m: dict) -> dict:
-    d, h, kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                       head_dim(m), m["d_ff"])
-    k_attn, k_mlp, _, _ = jax.random.split(key, 4)
-    a1, a2, a3, a4 = jax.random.split(k_attn, 4)
-    attn = {"wq": _dense(a1, d, h * hd), "wk": _dense(a2, d, kv * hd),
-            "wv": _dense(a3, d, kv * hd), "wo": _dense(a4, h * hd, d)}
-    if m["qkv_bias"]:
-        attn["bq"] = jnp.zeros((h * hd,), jnp.float32)
-        attn["bk"] = jnp.zeros((kv * hd,), jnp.float32)
-        attn["bv"] = jnp.zeros((kv * hd,), jnp.float32)
-    m1, m2, m3 = jax.random.split(k_mlp, 3)
-    mlp = {"w_down": _dense(m2, f, d), "w_up": _dense(m1, d, f),
-           "w_gate": _dense(m3, d, f)}
-    return {"ln1": _norm_init(m), "attn": attn, "ln2": _norm_init(m),
-            "mlp": mlp}
-
-
-def storage_dtypes(m: dict) -> dict:
-    """The dtype each weight is kept in, as a tree shaped like the
-    parameters: the final norm in float32, the rest in ``m["dtype"]``."""
-    dt = m["dtype"]
-    tree = jax.eval_shape(lambda: init_params(0, m))
-    out = jax.tree.map(lambda _: dt, tree)
-    out["final_norm"] = jax.tree.map(lambda _: "float32", tree["final_norm"])
-    return out
-
-
 def _round_to(x, dtype: str):
     """Round float32 ``x`` to the values ``dtype`` holds.  A cast there and
     back would do on the CPU, but XLA may drop such a pair on the TPU (it
@@ -117,35 +113,21 @@ def _round_to(x, dtype: str):
                                     mantissa_bits=f.nmant)
 
 
-@partial(jax.jit, static_argnums=(1,))
-def _init(key, m_items):
-    m = dict(m_items)
-    keys = jax.random.split(key, m["n_layers"] + 4)
-    params = {
-        "embed": jax.random.normal(keys[0], (m["vocab"], m["d_model"]))
-        * 0.02,
-        "final_norm": _norm_init(m),
-        "groups": [jax.vmap(lambda k: _layer_init(k, m))(
-            jax.random.split(keys[1], m["n_layers"]))],
-    }
-    if not m["tie_embeddings"]:
-        params["lm_head"] = jax.random.normal(
-            keys[-1], (m["d_model"], m["vocab"])) * 0.02
-    dt = m["dtype"]
-    out = jax.tree.map(lambda a: _round_to(a, dt), params)
-    out["final_norm"] = params["final_norm"]
-    return out
+@partial(jax.jit, static_argnums=(0, 2))
+def _init(fam, key, m_items):
+    return fam.init(key, thaw(m_items))
 
 
-def _hashable(m: dict):
-    return tuple(sorted((k, v) for k, v in m.items()
-                        if isinstance(v, (int, float, str, bool, type(None)))))
+def init_params(fam, seed: int, m: dict) -> dict:
+    """Family ``fam``'s float32 weights for ``seed``."""
+    return _init(fam, jax.random.PRNGKey(seed), freeze(m))
 
 
-def init_params(seed: int, m: dict) -> dict:
-    """Float32 weights holding the values the program's initialiser stores
-    for ``seed``: the same draws, rounded to the storage dtype."""
-    return _init(jax.random.PRNGKey(seed), _hashable(m))
+def leaf_names(fam, m: dict) -> list:
+    """Path names of the parameter leaves, in ``jax.tree.leaves`` order."""
+    tree = jax.eval_shape(lambda: init_params(fam, 0, m))
+    return [jax.tree_util.keystr(p) for p, _ in
+            jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
 # ----------------------------------------------------------------- numerics
@@ -190,10 +172,11 @@ def _rope(x, m):
 
 
 def _attention(q, k, v, numerics):
-    """Causal softmax attention, exact, one chunk of queries at a time.
-    q: (B, S, H, hd); k, v: (B, S, KV, hd)."""
+    """Causal softmax attention, exact, one chunk of queries at a time,
+    scores scaled by ``1/sqrt(hd)``.  q, k: (B, S, H or KV, hd); v: (B, S,
+    KV, vd)."""
     B, S, H, hd = q.shape
-    kv = k.shape[2]
+    kv, vd = k.shape[2], v.shape[-1]
     qb = min(Q_BLOCK, S)
     nq = S // qb
     qg = q.reshape(B, nq, qb, kv, H // kv, hd)
@@ -207,46 +190,14 @@ def _attention(q, k, v, numerics):
         w = jax.nn.softmax(s, axis=-1)
         return _mm("bkgqt,btkh->bqkgh", w, v, numerics)
 
-    out = jax.lax.map(block, jnp.arange(nq))       # (nq, B, qb, KV, G, hd)
-    return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, hd)
+    out = jax.lax.map(block, jnp.arange(nq))       # (nq, B, qb, KV, G, vd)
+    return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, vd)
 
 
-def _layer(x, p, m, numerics):
-    B, S, D = x.shape
-    hd = head_dim(m)
-    a = p["attn"]
-    h = _norm(p["ln1"], m, x)
-    q = _mm("bsd,de->bse", h, a["wq"], numerics)
-    k = _mm("bsd,de->bse", h, a["wk"], numerics)
-    v = _mm("bsd,de->bse", h, a["wv"], numerics)
-    if m["qkv_bias"]:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = _rope(q.reshape(B, S, m["n_heads"], hd), m)
-    k = _rope(k.reshape(B, S, m["n_kv_heads"], hd), m)
-    v = v.reshape(B, S, m["n_kv_heads"], hd)
-    o = _attention(q, k, v, numerics).reshape(B, S, m["n_heads"] * hd)
-    x = x + _mm("bse,ed->bsd", o, a["wo"], numerics)
-    h = _norm(p["ln2"], m, x)
-    mp = p["mlp"]
-    g = jax.nn.silu(_mm("bsd,df->bsf", h, mp["w_gate"], numerics))
-    u = _mm("bsd,df->bsf", h, mp["w_up"], numerics)
-    return x + _mm("bsf,fd->bsd", g * u, mp["w_down"], numerics)
-
-
-def _ce_sum(params, m, tokens, numerics):
+def _next_token_ce(x, head, tokens, numerics):
     """Sum of next-token cross entropies of ``tokens`` (B, S) over the
-    first ``S - 1`` positions."""
-    x = params["embed"][tokens]
-    if m["tie_embeddings"]:
-        x = x * math.sqrt(m["d_model"])
-
-    def body(x, p):
-        return jax.checkpoint(lambda x, p: _layer(x, p, m, numerics))(x, p), \
-            None
-
-    x, _ = jax.lax.scan(body, x, params["groups"][0])
-    x = _norm(params["final_norm"], m, x)
-    head = params["embed"].T if m["tie_embeddings"] else params["lm_head"]
+    first ``S - 1`` positions, from the final hidden states ``x`` (B, S, D)
+    through ``head`` (D, V), a chunk of positions at a time."""
     B, S, D = x.shape
     c = min(CE_CHUNK, S)
     targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
@@ -263,19 +214,6 @@ def _ce_sum(params, m, tokens, numerics):
         return jnp.sum((lz - gold) * ws[None, :])
 
     return jnp.sum(jax.lax.map(chunk, jnp.arange(S // c)))
-
-
-def mean_loss(params, m, blocks, numerics="f32"):
-    """Mean next-token cross entropy of the rows of ``blocks`` (n, rows,
-    S), summed one block of rows at a time.  A block keeps each layer's
-    input for the backward pass and recomputes the rest."""
-    n, rows, S = blocks.shape
-
-    def body(total, tb):
-        return total + _ce_sum(params, m, tb, numerics), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), blocks)
-    return total / (n * rows * (S - 1))
 
 
 # --------------------------------------------------------------- optimizer
@@ -303,12 +241,12 @@ def channel_norms(tree) -> list:
             for x in jax.tree.leaves(tree)]
 
 
-@partial(jax.jit, static_argnums=(2, 3, 4))
-def _grads(params, blocks, m_items, clip_norm, numerics):
+@partial(jax.jit, static_argnums=(0, 3, 4, 5))
+def _grads(fam, params, blocks, m_items, clip_norm, numerics):
     """Loss, pre-clip global gradient norm, the clipping scale, and the
     unclipped gradients."""
-    loss, grads = jax.value_and_grad(mean_loss)(
-        params, dict(m_items), blocks, numerics)
+    loss, grads = jax.value_and_grad(fam.mean_loss)(
+        params, thaw(m_items), blocks, numerics)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
                          for g in jax.tree.leaves(grads)))
     scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-9))
@@ -320,7 +258,7 @@ def _update(p, g, past, count, scale, dtype, job_items):
     """AdamW on one weight.  The moments are folded from the clipped
     gradients of every step so far (``past``, oldest first, then this
     step's), in the order in which the optimizer would have updated them."""
-    job = dict(job_items)
+    job = thaw(job_items)
     b1, b2 = job["b1"], job["b2"]
     g = g * scale
     mu = nu = jnp.zeros_like(p)
@@ -339,9 +277,10 @@ def _change_norms(a, b):
     return channel_norms(jax.tree.map(jnp.subtract, a, b))
 
 
-def train(m: dict, job: dict, seed: int, batches, rows_per_block: int,
+def train(fam, m: dict, job: dict, seed: int, batches, rows_per_block: int,
           numerics: str = "f32", devices=None) -> dict:
-    """Run ``len(batches)`` AdamW steps from the seed's weights.
+    """Run ``len(batches)`` AdamW steps of family ``fam`` from the seed's
+    weights.
 
     Returns per-step ``loss`` and pre-clip ``grad_norm``; the per-leaf
     norms of step 1's clipped gradient (``grad1``) and its per-channel norms
@@ -357,14 +296,14 @@ def train(m: dict, job: dict, seed: int, batches, rows_per_block: int,
     The rows of a block are spread over ``devices`` (default: the first
     device); every device holds all the weights, and XLA sums the partial
     gradients.  ``rows_per_block`` is a multiple of their number."""
-    check_supported(m)
+    fam.check_supported(m)
     mesh = jax.sharding.Mesh(np.asarray(devices or jax.devices()[:1]), ("d",))
     rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     spread = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec(None, "d", None))
-    mi, ji = _hashable(m), _hashable(job)
-    params = jax.device_put(init_params(seed, m), rep)
-    dtypes = jax.tree.leaves(storage_dtypes(m))
+    mi, ji = freeze(m), freeze(job)
+    params = jax.device_put(init_params(fam, seed, m), rep)
+    dtypes = jax.tree.leaves(fam.storage_dtypes(m))
     past = [[] for _ in dtypes]
     losses, gnorms, grad1, grad1_ch, seconds = [], [], None, None, []
     with jax.default_matmul_precision("highest"):
@@ -373,8 +312,8 @@ def train(m: dict, job: dict, seed: int, batches, rows_per_block: int,
             blocks = np.asarray(tokens).reshape(-1, rows_per_block,
                                                 tokens.shape[-1])
             loss, gnorm, scale, grads = _grads(
-                params, jax.device_put(blocks, spread), mi, job["clip_norm"],
-                numerics)
+                fam, params, jax.device_put(blocks, spread), mi,
+                job["clip_norm"], numerics)
             losses.append(float(loss))
             gnorms.append(float(gnorm))
             if grad1 is None:
@@ -401,14 +340,7 @@ def train(m: dict, job: dict, seed: int, batches, rows_per_block: int,
         # the start is made again rather than kept: a copy would cost the
         # memory of one more set of weights during the steps
         change = [np.asarray(c) for c in _change_norms(
-            params, jax.device_put(init_params(seed, m), rep))]
+            params, jax.device_put(init_params(fam, seed, m), rep))]
     return {"loss": losses, "grad_norm": gnorms, "grad1": grad1,
             "grad1_channels": grad1_ch, "change": change,
             "seconds": seconds}
-
-
-def leaf_names(m: dict) -> list:
-    """Path names of the parameter leaves, in ``jax.tree.leaves`` order."""
-    tree = jax.eval_shape(lambda: init_params(0, m))
-    return [jax.tree_util.keystr(p) for p, _ in
-            jax.tree_util.tree_flatten_with_path(tree)[0]]

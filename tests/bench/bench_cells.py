@@ -46,8 +46,9 @@ def add_tiny_cells(root):
         bench = json.load(f)
     for name, model in TINY.items():
         with open(os.path.join(b, "configs", f"{name}.json"), "w") as f:
-            json.dump({"model": model, "source": "test size",
-                       "reduced": {}, "assumed": {}}, f)
+            json.dump({"reference": "dense", "model": model,
+                       "source": "test size", "reduced": {}, "assumed": {}},
+                      f)
         bench["configs"].append({"name": name, "source": "test size",
                                  "file": f"bench/configs/{name}.json",
                                  "reduced": [], "why": "test size"})

@@ -42,7 +42,8 @@ def test_control_fails(tiny_root, cell):
     dev = jax.devices()[:1]
     ref = harness.reference_run(c, 1, pool, dev)
     ctl = harness.reference_run(c, 1, pool, dev, numerics="fp8")
-    names = harness.reference.leaf_names(c["config"]["model"])
+    names = harness.reference.leaf_names(c["reference"],
+                                         c["config"]["model"])
     ok, lines = check.verdict(harness.compare(c, names, ctl, ref),
                               TINY_LIMITS)
     assert not ok, lines
@@ -146,8 +147,9 @@ CHILD = textwrap.dedent("""
     pool = harness.TokenPool(cell["traffic"], 512, 8, 3)
     batches = [pool.global_step_batch(i) for i in range(3)]
     m, oc = cell["config"]["model"], cell["traffic"]["optimizer"]
-    one = harness.reference.train(m, oc, 3, batches, 4)
-    four = harness.reference.train(m, oc, 3, batches, 4,
+    fam = cell["reference"]
+    one = harness.reference.train(fam, m, oc, 3, batches, 4)
+    four = harness.reference.train(fam, m, oc, 3, batches, 4,
                                    devices=jax.devices()[:4])
     for r in (one, four):
         r["change"] = [float(np.sqrt(np.sum(np.square(c))))

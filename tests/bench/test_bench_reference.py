@@ -1,6 +1,7 @@
 """The plain reference against the program at test size on the CPU: the
 same weights from a seed, the same loss and gradients in float32, and the
-same training steps through the trainer's own compiled step."""
+same training steps through the trainer's own compiled step; and the form
+in which a configuration reaches the reference's jitted functions."""
 import dataclasses
 
 import jax
@@ -8,8 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, reference as R
+from bench import check, reference as R, spec
 from bench_cells import OPTIMIZER, TINY
+
+DENSE = spec.reference_family(spec.BENCH, "dense")
 
 
 def _cfg(name, **kw):
@@ -25,12 +28,12 @@ def test_weights_from_the_seed(name):
     cfg = _cfg(name)
     prog = jax.jit(ST.init_params, static_argnums=1)(
         jax.random.PRNGKey(2**31 + 7), cfg)
-    ref = R.init_params(2**31 + 7, dataclasses.asdict(cfg))
+    ref = R.init_params(DENSE, 2**31 + 7, dataclasses.asdict(cfg))
     pl = jax.tree_util.tree_flatten_with_path(prog)[0]
     rl = jax.tree_util.tree_flatten_with_path(ref)[0]
     assert [jax.tree_util.keystr(k) for k, _ in pl] == \
         [jax.tree_util.keystr(k) for k, _ in rl]
-    assert R.leaf_names(dataclasses.asdict(cfg)) == \
+    assert R.leaf_names(DENSE, dataclasses.asdict(cfg)) == \
         [jax.tree_util.keystr(k) for k, _ in pl]
     for (_, a), (_, b) in zip(pl, rl):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
@@ -45,13 +48,13 @@ def test_loss_and_gradients_in_float32(name):
     m = dataclasses.asdict(cfg)
     toks = np.random.default_rng(3).integers(0, cfg.vocab, (4, 64),
                                              dtype=np.int32)
-    params = R.init_params(5, m)
+    params = R.init_params(DENSE, 5, m)
     with jax.default_matmul_precision("highest"):
         lp, gp = jax.jit(jax.value_and_grad(
             lambda p, t: ST.loss_fn(p, cfg, {"tokens": t})))(params, toks)
         lr, gr = jax.jit(jax.value_and_grad(
-            lambda p, t: R.mean_loss(p, m, t.reshape(2, 2, 64))))(params,
-                                                                  toks)
+            lambda p, t: DENSE.mean_loss(p, m, t.reshape(2, 2, 64))))(
+                params, toks)
     np.testing.assert_allclose(float(lp), float(lr), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -100,8 +103,26 @@ def test_training_steps_in_float32(name):
                for _ in range(3)]
     with jax.default_matmul_precision("highest"):
         got = _program_steps(cfg, 9, batches)
-    ref = R.train(m, OPTIMIZER, 9, batches, 1)
-    found = check.readings(got, ref, R.leaf_names(m))
+    ref = R.train(DENSE, m, OPTIMIZER, 9, batches, 1)
+    found = check.readings(got, ref, R.leaf_names(DENSE, m))
     assert found["loss_gap"][0] < 1e-6, found
     assert found["grad_gap"][0] < 1e-4, found
     assert found["change_gap"][0] < 1e-3, found
+
+
+def test_frozen_settings_keep_nested_blocks():
+    """A nested block reaches a jitted function as a dict, a list as a
+    tuple; the frozen form is hashable and thaws to what it was made
+    from."""
+    from repro.configs import get_config
+
+    m = dataclasses.asdict(get_config("deepseek-v2-lite-16b").reduced())
+    frozen = R.freeze(m)
+    hash(frozen)
+    assert R.thaw(frozen) == m
+    assert R.freeze({"pattern": ["rec", "attn"]}) == (
+        ("pattern", ("rec", "attn")),)
+
+    top_k = jax.jit(lambda x, mi: x * R.thaw(mi)["moe"]["top_k"],
+                    static_argnums=1)
+    assert float(top_k(jnp.float32(1.0), frozen)) == m["moe"]["top_k"]

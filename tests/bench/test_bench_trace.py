@@ -1,8 +1,8 @@
-"""The trace reduction on a trace whose answers are worked out by hand, and
-the table of peaks."""
+"""The trace reduction on a trace whose answers are worked out by hand, the
+table of peaks, and the dense family's operation count."""
 import pytest
 
-from bench import flops, trace
+from bench import flops, spec, trace
 
 NS = 1e-9
 # Device 0 runs two steps of 100 ns with a 20 ns gap.  Step 1: compute,
@@ -75,11 +75,12 @@ def test_peaks_table():
 
 
 def test_flops_per_token_qwen2():
+    dense = spec.reference_family(spec.BENCH, "dense")
     m = {"n_layers": 24, "d_model": 896, "n_heads": 14, "n_kv_heads": 2,
          "d_ff": 4864, "vocab": 151936, "head_dim": 0, "glu": True}
-    assert flops.matmul_params(m) == 24 * (896 * 64 * 18 + 14 * 64 * 896
+    assert dense.matmul_params(m) == 24 * (896 * 64 * 18 + 14 * 64 * 896
                                            + 3 * 896 * 4864) + 151936 * 896
-    assert flops.flops_per_token(m, 1024) == 6 * 493961216 \
+    assert dense.flops_per_token(m, 1024) == 6 * 493961216 \
         + 6 * 1024 * 896 * 24
 
 
