@@ -59,7 +59,8 @@ def _nbytes(v) -> float:
 
 
 def _classify(prim_name: str) -> str:
-    if prim_name in ("dot_general", "conv_general_dilated", "ragged_dot"):
+    if prim_name in ("dot_general", "conv_general_dilated",
+                     "ragged_dot_general"):
         return DOT
     if prim_name in _EW_PRIMS:
         return EW
@@ -70,7 +71,24 @@ def _classify(prim_name: str) -> str:
     return OPAQUE
 
 
+def _ragged_dot_flops(eqn) -> float:
+    """A grouped matmul priced by the rows its groups cover: each element
+    of the lhs meets one group's matrix, so ``2 * |lhs| * N`` with ``N``
+    the rhs's free size (its dims that are not contracted, batched or
+    groups).  The group sizes are known only at run time: every static
+    row counts as covered."""
+    rdn = eqn.params["ragged_dot_dimension_numbers"]
+    (_, rhs_c), (_, rhs_b) = rdn.dot_dimension_numbers
+    lhs, rhs = eqn.invars[0].aval, eqn.invars[1].aval
+    bound = set(rhs_c) | set(rhs_b) | set(rdn.rhs_group_dimensions)
+    free = [n for i, n in enumerate(rhs.shape) if i not in bound]
+    return 2.0 * float(np.prod(lhs.shape, dtype=np.float64)) \
+        * float(np.prod(free, dtype=np.float64))
+
+
 def _dot_flops(eqn) -> float:
+    if eqn.primitive.name == "ragged_dot_general":
+        return _ragged_dot_flops(eqn)
     out = eqn.outvars[0].aval
     dnums = eqn.params["dimension_numbers"]
     (lhs_c, _), _ = dnums

@@ -42,19 +42,23 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                       interpret=_interpret())
 
 
-def causal_block(S: int) -> Optional[_splash.BlockSizes]:
+def causal_block(S: int, head_dim: int = 128
+                 ) -> Optional[_splash.BlockSizes]:
     """The causal kernel's blocks for sequence length ``S``, or None where
     no block of 128 lanes or more divides it.  One square block for the
     forward and the fused dq/dkv backward: the largest of 1024, 512, 256
     and 128 that divides ``S`` and is at most half of it (128 alone up to
     256 tokens).  Measured on a v5e chip at heads of 64 (PERF.md): 1024 at
     4096 tokens, 512 at 1024; the fused backward beats the separate dq and
-    dkv kernels at both."""
+    dkv kernels at both.  Heads wider than 128 (MLA's 192) cap the
+    backward's block at 512: at 1024 its VMEM stack (18.4 MiB at 192)
+    passes the compiler's 16 MiB scoped limit."""
     for blk in (1024, 512, 256, 128):
         if S % blk == 0 and (2 * blk <= S or blk == 128):
+            bwd = blk if head_dim <= 128 else min(blk, 512)
             return _splash.BlockSizes(
                 block_q=blk, block_kv=blk, block_kv_compute=blk,
-                block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+                block_q_dkv=bwd, block_kv_dkv=bwd, block_kv_dkv_compute=bwd,
                 use_fused_bwd_kernel=True)
     return None
 
@@ -76,7 +80,7 @@ def _causal_kernel(S: int, H: int, sizes: _splash.BlockSizes,
 def causal_attention(q, k, v, sizes: Optional[_splash.BlockSizes] = None):
     """Causal GQA self-attention through the block-skipping splash kernel.
     q: (B,S,H,hd); k,v: (B,S,KV,hd) with H % KV == 0.  ``sizes`` defaults
-    to ``causal_block(S)``.  bf16 operands meet the MXU as they are; the
+    to ``causal_block(S, hd)``.  bf16 operands meet the MXU as they are; the
     softmax and the accumulators are f32.
 
     A Mosaic call cannot be partitioned, so where the ambient mesh leaves
@@ -84,7 +88,7 @@ def causal_attention(q, k, v, sizes: Optional[_splash.BlockSizes] = None):
     data axes) the kernel runs in a nested ``shard_map`` over them, heads
     split over ``model`` where it divides both head counts."""
     _, S, H, hd = q.shape
-    kernel = _causal_kernel(S, H, sizes or causal_block(S), _interpret())
+    kernel = _causal_kernel(S, H, sizes or causal_block(S, hd), _interpret())
     scale = 1.0 / np.sqrt(hd)
 
     def run(q, k, v):

@@ -27,8 +27,26 @@ class MoEConfig:
     top_k: int = 6
     d_expert: int = 1408          # per-expert FFN hidden size
     first_dense_layers: int = 1   # DeepSeek-V2: layer 0 is dense
-    capacity_factor: float = 1.25
-    aux_loss_coef: float = 0.001
+    aux_loss_coef: float = 0.001  # balance loss alpha
+    # experts held here, a contiguous range of the ``n_routed`` that the
+    # router chooses from (``first_expert`` of init_moe/moe_fwd); 0: all
+    n_held: int = 0
+    routed_scaling: float = 1.0   # routed experts' output scale
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_routed
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (arXiv:2309.00071) as DeepSeek-V2 publishes it."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +81,7 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_frac: float = 1.0        # fraction of head_dim rotated (StableLM: 0.25)
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None   # None: plain rope
     tie_embeddings: bool = False
     window: Optional[int] = None  # sliding-window size for "attn" blocks
     block: str = "attn"           # attn | mla | rwkv (or hybrid via recurrent)
@@ -119,7 +138,7 @@ class ModelConfig:
             if self.is_moe_layer(i):
                 e = self.moe
                 nff = 3 if self.glu else 2
-                total += e.n_routed * nff * d * e.d_expert
+                total += e.held * nff * d * e.d_expert
                 total += e.n_shared * nff * d * e.d_expert
                 total += d * e.n_routed
             elif kind != "rwkv":
@@ -135,13 +154,15 @@ class ModelConfig:
         return float(total)
 
     def active_param_count(self) -> float:
-        """Active params per token (MoE: only top-k + shared experts)."""
+        """Active params per token (MoE: only top-k + shared experts; of
+        the experts held, their expected share of the top-k)."""
         if self.moe is None:
             return self.param_count()
         e = self.moe
         nff = 3 if self.glu else 2
         n_moe_layers = self.n_layers - e.first_dense_layers
-        inactive = (e.n_routed - e.top_k) * nff * self.d_model * e.d_expert
+        inactive = e.held * (e.n_routed - e.top_k) / e.n_routed \
+            * nff * self.d_model * e.d_expert
         return self.param_count() - n_moe_layers * inactive
 
     def reduced(self) -> "ModelConfig":
@@ -165,7 +186,7 @@ class ModelConfig:
         if self.moe is not None:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_routed=4, n_shared=1, top_k=2, d_expert=128,
-                capacity_factor=8.0)  # generous: no token drops at toy scale
+                n_held=0)
         if self.encdec is not None:
             kw["encdec"] = EncDecConfig(n_enc_layers=2, enc_seq=32,
                                         frontend_dim=256)

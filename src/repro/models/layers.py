@@ -11,6 +11,7 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -44,9 +45,50 @@ def norm_fwd(p: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # -------------------------------------------------------------------- rope
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's temperature factor, as DeepSeek-V2's ``yarn_get_mscale``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
 def rope_freqs(cfg: ModelConfig, rot_dim: int) -> jnp.ndarray:
+    """Inverse frequencies of the ``rot_dim / 2`` rotated pairs; under
+    ``cfg.rope_scaling`` YaRN's: the slow dims (wavelengths past the
+    original context) divided by ``factor``, the fast ones kept, a linear
+    ramp between."""
     inv = 1.0 / (cfg.rope_theta ** (np.arange(0, rot_dim, 2) / rot_dim))
+    ys = cfg.rope_scaling
+    if ys is not None:
+        def dim(rotations):   # the dim that turns ``rotations`` times
+            return rot_dim * math.log(
+                ys.original_max_position_embeddings
+                / (rotations * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+        low = max(math.floor(dim(ys.beta_fast)), 0)
+        high = min(math.ceil(dim(ys.beta_slow)), rot_dim - 1)
+        if high == low:
+            high += 0.001
+        keep = 1 - np.clip((np.arange(rot_dim // 2) - low) / (high - low),
+                           0, 1)
+        inv = inv / ys.factor * (1 - keep) + inv * keep
     return jnp.asarray(inv, jnp.float32)
+
+
+def rope_mscale(cfg: ModelConfig) -> float:
+    """The factor on YaRN's cos and sin (1 without ``rope_scaling``)."""
+    ys = cfg.rope_scaling
+    if ys is None:
+        return 1.0
+    return yarn_mscale(ys.factor, ys.mscale) \
+        / yarn_mscale(ys.factor, ys.mscale_all_dim)
+
+
+def attn_mscale(cfg: ModelConfig) -> float:
+    """The factor on the softmax scale: YaRN's ``mscale_all_dim`` squared
+    (1 without ``rope_scaling``)."""
+    ys = cfg.rope_scaling
+    if ys is None or not ys.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, cfg: ModelConfig,
@@ -64,6 +106,9 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, cfg: ModelConfig,
         ang = positions[..., None].astype(jnp.float32) * inv
         ang = ang[:, :, None, :]
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    mscale = rope_mscale(cfg)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
     xr, xp = x[..., :rot], x[..., rot:]
     x1, x2 = xr[..., ::2], xr[..., 1::2]
     r1 = x1 * cos - x2 * sin
@@ -410,8 +455,12 @@ def mla_fwd(p: dict, cfg: ModelConfig, x, positions, *,
         # train/prefill: fold (nope ++ rope) into one effective head dim and
         # reuse the (flash-chunked) sdpa path — scores = qn.kn + qr.kr, and
         # long sequences must not materialise the (S, T) matrix densely.
-        # (sdpa's 1/sqrt(hd_eff) scale == MLA's 1/sqrt(nope+rope).)
+        # (sdpa's 1/sqrt(hd_eff) scale == MLA's 1/sqrt(nope+rope); YaRN's
+        # factor on it goes into q, in float32.)
         q_eff = jnp.concatenate([q_nope, q_rope], axis=-1)
+        mscale = attn_mscale(cfg)
+        if mscale != 1.0:
+            q_eff = (q_eff.astype(jnp.float32) * mscale).astype(q_eff.dtype)
         k_eff = jnp.concatenate(
             [k_nope, jnp.broadcast_to(
                 k_rope_all, (B, T, H, m.qk_rope_head_dim))], axis=-1)
@@ -420,7 +469,8 @@ def mla_fwd(p: dict, cfg: ModelConfig, x, positions, *,
         out = sdpa(q_eff, k_eff, v_pad, None, causal=True)[..., :m.v_head_dim]
         out = out.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
         return (out, new_cache) if return_cache else out
-    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = attn_mscale(cfg) / np.sqrt(m.qk_nope_head_dim
+                                       + m.qk_rope_head_dim)
     s_nope = jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
     s_rope = jnp.einsum("bshd,btxd->bhst", q_rope,
                         jnp.broadcast_to(k_rope_all, (B, T, 1, m.qk_rope_head_dim)))
@@ -458,70 +508,113 @@ def mlp_fwd(p: dict, cfg: ModelConfig, x):
 
 
 # --------------------------------------------------------------------- MoE
-def init_moe(key, cfg: ModelConfig) -> dict:
+def _experts(key, shape, fan_in, first: int, held: int, n: int):
+    """Held experts ``first .. first + held - 1`` of ``n``, each drawn from
+    its own key: an expert's weights do not depend on which share holds
+    it."""
+    keys = jax.random.split(key, n)[first:first + held]
+    return jax.vmap(lambda k: jax.random.normal(k, shape))(keys) \
+        / np.sqrt(fan_in)
+
+
+def init_moe(key, cfg: ModelConfig, first_expert: int = 0) -> dict:
+    """Router over all ``n_routed`` experts, the ``held`` experts from
+    ``first_expert`` on, and the shared experts."""
     e = cfg.moe
     keys = jax.random.split(key, 5)
     de, d = e.d_expert, cfg.d_model
+    held = (first_expert, e.held, e.n_routed)
     p = {
         "router": _dense(keys[0], d, e.n_routed, scale=0.02),
-        "w_up": jax.random.normal(keys[1], (e.n_routed, d, de)) / np.sqrt(d),
-        "w_down": jax.random.normal(keys[2], (e.n_routed, de, d)) / np.sqrt(de),
+        "w_up": _experts(keys[1], (d, de), d, *held),
+        "w_down": _experts(keys[2], (de, d), de, *held),
     }
     if cfg.glu:
-        p["w_gate"] = jax.random.normal(keys[3], (e.n_routed, d, de)) / np.sqrt(d)
+        p["w_gate"] = _experts(keys[3], (d, de), d, *held)
     if e.n_shared:
         p["shared"] = init_mlp(keys[4], cfg, d_ff=e.n_shared * e.d_expert)
     return p
 
 
-def moe_fwd(p: dict, cfg: ModelConfig, x):
-    """Top-k routed experts with sort-based dispatch (MaxText-style).
+def balance_loss(cfg: ModelConfig, probs, topi):
+    """DeepSeek-V2's sequence-wise expert balance loss over all ``top_k``
+    choices.  probs: (B, S, E) router softmax; topi: (B, S, k) chosen
+    experts.  ``alpha * mean_b sum_e f[b,e] * P[b,e]`` with ``f`` the
+    expert's share of the sequence's choices over ``k/E`` and ``P`` its
+    mean probability."""
+    e = cfg.moe
+    B, S, k = topi.shape
+    E = e.n_routed
+    counts = jnp.sum(jax.nn.one_hot(topi.reshape(B, S * k), E,
+                                    dtype=jnp.float32), axis=1)
+    f = counts / (S * k / E)
+    return e.aux_loss_coef * jnp.mean(
+        jnp.sum(f * jnp.mean(probs, axis=1), axis=-1))
 
-    Tokens are sorted by assigned expert and packed into a per-expert
-    capacity buffer (E, C, D); expert FFNs run as one batched einsum over the
-    expert dimension (shardable over the ``model`` axis — expert parallelism);
-    results scatter-add back to token order.  No one-hot matmuls, so compiled
-    FLOPs reflect only the active experts.  Returns (out, aux_loss)."""
+
+@obs.scope("moe")
+def moe_fwd(p: dict, cfg: ModelConfig, x, first_expert: int = 0):
+    """Top-k routed experts, dropless, over the experts held here.
+
+    The router is whole: float32 logits over all ``n_routed`` experts,
+    softmax, greedy top-k; the gate weights are the top-k probabilities,
+    not renormalised (DeepSeek-V2's ``norm_topk_prob`` false), scaled by
+    ``routed_scaling``; the balance loss is per sequence over all k
+    choices (its ``seq_aux``).  The (token, choice) pairs are sorted by
+    expert; those of the ``held`` experts from ``first_expert`` on lead,
+    one group per expert, and the expert FFNs run as grouped matmuls
+    (``jax.lax.ragged_dot``) over those rows.  A token
+    picks ``top_k`` distinct experts, so at most ``T * min(top_k, held)``
+    pairs are held: the static row count.  The grouped matmuls cover at
+    least half of it, the last group taking the zero rows that make up the
+    rest: a layer costs the same while its held experts get up to half the
+    pairs they could get, so the step's cost does not follow the routing,
+    which drifts as the router trains.  Pairs routed to experts not held
+    here add nothing (their chips' part).  The gated results scatter-add
+    back to token order in float32, and the shared experts are added
+    once.  Returns (out, balance loss)."""
     e = cfg.moe
     B, S, D = x.shape
-    T = B * S
-    k = e.top_k
-    E = e.n_routed
-    C = max(int(np.ceil(e.capacity_factor * k * T / E)), min(8, T * k))
+    T, k, H = B * S, e.top_k, e.held
+    R = T * min(k, H)
     xt = x.reshape(T, D)
-    logits = (xt @ p["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)                 # (T,k)
-    topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-9)
-    # load-balance aux loss (Switch-style)
-    density = jnp.mean(jax.nn.one_hot(topi[:, 0], E), axis=0)
-    router_prob = jnp.mean(probs, axis=0)
-    aux = e.aux_loss_coef * E * jnp.sum(density * router_prob)
-
-    flat_e = topi.reshape(-1)                            # (T*k,)
-    flat_w = topv.reshape(-1).astype(x.dtype)
-    flat_tok = jnp.arange(T * k, dtype=jnp.int32) // k
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(sorted_e, length=E)
-    offsets = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                               jnp.cumsum(counts)[:-1]])
-    pos_in_e = jnp.arange(T * k, dtype=jnp.int32) - offsets[sorted_e]
-    keep = (pos_in_e < C).astype(x.dtype)
-    slot = sorted_e * C + jnp.minimum(pos_in_e, C - 1)
-    tok_sorted = flat_tok[order]
-    buf = jnp.zeros((E * C, D), x.dtype).at[slot].add(
-        xt[tok_sorted] * keep[:, None])
-    xe = buf.reshape(E, C, D)
-    up = jnp.einsum("ecd,edf->ecf", xe, p["w_up"].astype(x.dtype))
-    if cfg.glu:
-        gate = jnp.einsum("ecd,edf->ecf", xe, p["w_gate"].astype(x.dtype))
-        h = _act(cfg, gate) * up
-    else:
-        h = _act(cfg, up)
-    out_e = jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(x.dtype))
-    contrib = out_e.reshape(E * C, D)[slot] * (flat_w[order] * keep)[:, None]
-    out = jnp.zeros((T, D), x.dtype).at[tok_sorted].add(contrib)
+    with jax.named_scope("route"):
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, k)              # (T, k)
+        topv = topv * e.routed_scaling
+        aux = balance_loss(cfg, probs.reshape(B, S, -1),
+                           topi.reshape(B, S, k))
+    obs.count("moe.grouped")
+    with jax.named_scope("dispatch"):
+        local = topi.reshape(-1) - first_expert           # (T*k,)
+        held = (local >= 0) & (local < H)
+        group = jnp.where(held, local, H).astype(jnp.int32)
+        order = jnp.argsort(group, stable=True)[:R]
+        sizes = jnp.bincount(group, length=H + 1)[:H].astype(jnp.int32)
+        sizes = sizes.at[H - 1].add(jnp.maximum(R // 2 - jnp.sum(sizes), 0))
+        tok = order // k
+        hold = held[order][:, None]                       # (R, 1)
+        w = jnp.where(hold[:, 0], topv.reshape(-1)[order], 0.0)
+        # rows past the held groups are left unwritten by the TPU's grouped
+        # matmul (NaN on the chip): every operand and result of one, and so
+        # every cotangent, is selected to 0 there, never multiplied
+        xs = jnp.where(hold, xt[tok], 0)                  # (R, D)
+    with jax.named_scope("experts"):
+        up = jax.lax.ragged_dot(xs, p["w_up"].astype(x.dtype), sizes)
+        if cfg.glu:
+            gate = jax.lax.ragged_dot(xs, p["w_gate"].astype(x.dtype), sizes)
+            h = _act(cfg, gate) * up
+        else:
+            h = _act(cfg, up)
+        h = jnp.where(hold, h, 0)
+        ys = jnp.where(hold, jax.lax.ragged_dot(
+            h, p["w_down"].astype(x.dtype), sizes), 0)
+    with jax.named_scope("combine"):
+        out = jnp.zeros((T, D), jnp.float32).at[tok].add(
+            ys.astype(jnp.float32) * w[:, None]).astype(x.dtype)
     if e.n_shared:
         out = out + mlp_fwd(p["shared"], cfg, xt)
     return out.reshape(B, S, D), aux

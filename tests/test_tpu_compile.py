@@ -162,6 +162,40 @@ def test_causal_attention_compiles(B, S, H, KV, one_chip, monkeypatch):
     assert _custom_calls(compiled) >= 2
 
 
+def test_mla_attention_compiles(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's MLA at 2 x 8192 on one chip: 16 heads of 192
+    (nope 128 + rope 64; v padded to 192) through the splash kernel's
+    forward and fused backward, whose blocks ``causal_block`` caps at 512
+    for heads this wide."""
+    monkeypatch.setattr(K, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((2, 8192, 16, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    assert K.causal_block(8192, 192).block_q_dkv == 512
+    assert K.causal_block(8192).block_q_dkv == 1024
+    compiled = jax.jit(_attention_grads).lower(q, q, q).compile()
+    assert _custom_calls(compiled) >= 2
+
+
+def test_grouped_expert_matmuls_compile(one_chip):
+    """The expert layer's grouped matmuls at DeepSeek-V2-Lite's widths
+    (d_model 2048, experts of 1408, 8 held) over the 2 x 8192 x 6 row
+    bound, forward and backward, as the chip's compiler takes them."""
+    rows, d, de, held = 2 * 8192 * 6, 2048, 1408, 8
+
+    def layer(x, wg, wu, wd, sizes):
+        h = jax.nn.silu(jax.lax.ragged_dot(x, wg, sizes)) \
+            * jax.lax.ragged_dot(x, wu, sizes)
+        return jnp.sum(jax.lax.ragged_dot(h, wd, sizes).astype(jnp.float32))
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(layer, argnums=(0, 1, 2, 3))).lower(
+        arg((rows, d)), arg((held, d, de)), arg((held, d, de)),
+        arg((held, de, d)), arg((held,), jnp.int32)).compile()
+    assert "ragged" in compiled.as_text()
+
+
 @pytest.mark.parametrize("dp,tp", [(4, 1), (2, 2)])
 def test_causal_attention_partitions_in_data_shard_map(dp, tp, topo,
                                                        monkeypatch):
